@@ -53,6 +53,8 @@ def main() -> None:
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
 
+    from repro import compile_cache
+    compile_cache.configure()
     from benchmarks.common import emit, load_context
 
     print("name,us_per_call,derived")

@@ -136,9 +136,10 @@ def region_filter_mask_batch(proposals, prop_valid, accepted, acc_valid,
 def crop_gather(frames, boxes, idxs, *, out_hw, impl: str = "ref"):
     """Compacted crop gather: (F,H,W,C) x (F,N,4) x (3,B) -> (B,oh,ow,C).
 
-    All impls share the fixed-lowering bilinear program in
-    ``ref.bilinear_crops``, so ref / interpret / pallas outputs are
-    bit-identical to gathering from the full shared crop grid.
+    All impls share the bilinear program of ``ref.crop_positions`` and
+    ``ref.bilinear_sample``, so ref and interpret outputs are bit-identical
+    to gathering from the full shared crop grid (pallas too, where its
+    one-hot tap matmuls run at f32 precision).
     """
     if impl in ("ref", "ref_unchunked"):
         return ref.crop_gather(frames, boxes, idxs, out_hw=out_hw)

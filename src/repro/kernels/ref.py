@@ -353,78 +353,79 @@ def _crop_lin(n: int) -> np.ndarray:
     return np.linspace(0.0, 1.0, n, dtype=np.float32)
 
 
-def bilinear_crops(frames: jax.Array,    # (F, H, W, C)
-                   fmap: jax.Array,      # (K,) int32 in-range frame index
-                   boxes: jax.Array,     # (K, 4) xyxy in [0, 1]
-                   out_hw: Tuple[int, int],
-                   *,
-                   lin_y: Optional[jax.Array] = None,   # (oh,) sample grid
-                   lin_x: Optional[jax.Array] = None) -> jax.Array:
-    """Bilinear-resample K boxes to ``out_hw``; returns (K, oh, ow, C).
+# XLA:CPU contracts a multiply that feeds an add into one FMA wherever the
+# two share a fusion (``optimization_barrier`` does not stop it), so two
+# programs that fuse the same formula differently round it differently.
+# Adding a zero that is only known at run time to every product that feeds
+# an add pins the rounding: contracted or not, ``a * b + 0`` is the rounded
+# product, and an add of two such sums has no multiply left to contract.
+def crop_positions(boxes: jax.Array,          # (..., 4) xyxy in [0, 1]
+                   h_img: int, w_img: int,
+                   lin_y: jax.Array,        # (oh,) sample grid
+                   lin_x: jax.Array         # (ow,)
+                   ) -> Tuple[jax.Array, jax.Array]:
+    """Sample rows ``ys`` (..., oh) and columns ``xs`` (..., ow) of boxes.
 
-    This is THE crop program: the shared-grid path (``crop_batch``), the
-    compacted gather oracle (``crop_gather``) and the Pallas kernel body all
-    call it, so every path computes bit-identical pixels.  Two properties
-    make that hold across different surrounding program structures on CPU:
+    The grid is separable: a crop's row positions depend only on its
+    output row and its column positions only on its output column."""
+    x1, y1 = boxes[..., 0:1], boxes[..., 1:2]
+    x2, y2 = boxes[..., 2:3], boxes[..., 3:4]
+    z = 0.0 * y1                             # the run-time zero (see above)
+    ys = (y1 * (h_img - 1) + z) + (((y2 - y1) * (h_img - 1)) * lin_y + z)
+    xs = (x1 * (w_img - 1) + z) + (((x2 - x1) * (w_img - 1)) * lin_x + z)
+    return ys, xs
 
-      * the sample grid is a baked float32 literal (see ``_crop_lin``), and
-      * ``lax.optimization_barrier`` separates every multiply from the add
-        it feeds — XLA's fusion emitters may otherwise contract ``a*b + c``
-        into an FMA, and whether they do depends on how the op got batched
-        (the exact "flat per-pair cropping lowers differently under XLA
-        fusion" constraint that forced the old full-grid materialization).
 
-    ``lin_y``/``lin_x`` default to ``_crop_lin``; the Pallas kernel body
-    passes them as explicit kernel operands instead (a kernel can't capture
-    array constants) — same bits either way.
+def bilinear_sample(ys: jax.Array, xs: jax.Array, fetch) -> jax.Array:
+    """Blend the four taps around every (ys, xs) sample point.
 
-    Math is bit-identical to ``jax.scipy.ndimage.map_coordinates(order=1,
-    mode='constant')`` evaluated eagerly."""
-    f, h_img, w_img, ch = frames.shape
-    k = boxes.shape[0]
-    oh, ow = out_hw
-    if lin_y is None:
-        lin_y = jnp.asarray(_crop_lin(oh))
-    if lin_x is None:
-        lin_x = jnp.asarray(_crop_lin(ow))
-    x1, y1, x2, y2 = (boxes[:, 0], boxes[:, 1], boxes[:, 2], boxes[:, 3])
-    ya = (y1 * (h_img - 1))[:, None]                        # (K, 1)
-    yb = ((y2 - y1) * (h_img - 1))[:, None] * lin_y          # (K, oh)
-    xa = (x1 * (w_img - 1))[:, None]
-    xb = ((x2 - x1) * (w_img - 1))[:, None] * lin_x
-    ya, yb, xa, xb = jax.lax.optimization_barrier((ya, yb, xa, xb))
-    ys = ya + yb                                            # (K, oh)
-    xs = xa + xb                                            # (K, ow)
-    yy = jnp.broadcast_to(ys[:, :, None], (k, oh, ow)).reshape(k, oh * ow)
-    xx = jnp.broadcast_to(xs[:, None, :], (k, oh, ow)).reshape(k, oh * ow)
-    y_lo_f = jnp.floor(yy)
-    x_lo_f = jnp.floor(xx)
-    wy_hi = yy - y_lo_f
+    ``ys`` and ``xs`` broadcast against each other; ``fetch(yi, xi)``
+    returns the frame values at integer rows ``yi`` and columns ``xi``, and
+    0 where a tap falls off the frame (``mode='constant'``).  The crop
+    oracle fetches with a gather and the Pallas kernel with one-hot
+    selection matmuls; both blend here."""
+    y_lo_f = jnp.floor(ys)
+    x_lo_f = jnp.floor(xs)
+    wy_hi = ys - y_lo_f
     wy_lo = 1 - wy_hi
-    wx_hi = xx - x_lo_f
+    wx_hi = xs - x_lo_f
     wx_lo = 1 - wx_hi
     y_lo = y_lo_f.astype(jnp.int32)
     x_lo = x_lo_f.astype(jnp.int32)
-    y_hi = y_lo + 1
-    x_hi = x_lo + 1
-    fk = fmap[:, None]
+    z = 0.0 * ys                             # the run-time zero (see above)
+    t00 = (wy_lo * wx_lo) * fetch(y_lo, x_lo) + z
+    t01 = (wy_lo * wx_hi) * fetch(y_lo, x_lo + 1) + z
+    t10 = (wy_hi * wx_lo) * fetch(y_lo + 1, x_lo) + z
+    t11 = (wy_hi * wx_hi) * fetch(y_lo + 1, x_lo + 1) + z
+    return ((t00 + t01) + t10) + t11
 
-    def term(yi, wy, xi, wx):
-        # mode='constant': out-of-frame taps contribute cval=0 (boxes are
-        # clipped to [0,1], so only the +1 taps on the far edge hit this)
+
+def bilinear_crops(frames: jax.Array,    # (F, H, W, C)
+                   fmap: jax.Array,      # (K,) int32 in-range frame index
+                   boxes: jax.Array,     # (K, 4) xyxy in [0, 1]
+                   out_hw: Tuple[int, int]) -> jax.Array:
+    """Bilinear-resample K boxes to ``out_hw``; returns (K, oh, ow, C).
+
+    This is THE crop program of the shared-grid path (``crop_batch``) and
+    the compacted gather oracle (``crop_gather``); the Pallas kernel runs
+    the same :func:`crop_positions` and :func:`bilinear_sample`, so every
+    path computes bit-identical pixels where its tap fetch is exact.  The
+    sample grid is a baked float32 literal (see ``_crop_lin``).  Math is
+    bit-identical to ``jax.scipy.ndimage.map_coordinates(order=1,
+    mode='constant')`` evaluated eagerly."""
+    _, h_img, w_img, _ = frames.shape
+    oh, ow = out_hw
+    ys, xs = crop_positions(boxes, h_img, w_img, jnp.asarray(_crop_lin(oh)),
+                            jnp.asarray(_crop_lin(ow)))
+    fk = fmap[:, None, None]
+
+    def fetch(yi, xi):               # yi (K, oh, 1, 1), xi (K, 1, ow, 1)
+        yi, xi = yi[..., 0], xi[..., 0]
         valid = (yi >= 0) & (yi < h_img) & (xi >= 0) & (xi < w_img)
-        yc = jnp.clip(yi, 0, h_img - 1)
-        xc = jnp.clip(xi, 0, w_img - 1)
-        contrib = jnp.where(valid[..., None], frames[fk, yc, xc], 0.0)
-        return (wy * wx)[..., None] * contrib
+        px = frames[fk, jnp.clip(yi, 0, h_img - 1), jnp.clip(xi, 0, w_img - 1)]
+        return jnp.where(valid[..., None], px, 0.0)
 
-    t00 = term(y_lo, wy_lo, x_lo, wx_lo)
-    t01 = term(y_lo, wy_lo, x_hi, wx_hi)
-    t10 = term(y_hi, wy_hi, x_lo, wx_lo)
-    t11 = term(y_hi, wy_hi, x_hi, wx_hi)
-    t00, t01, t10, t11 = jax.lax.optimization_barrier((t00, t01, t10, t11))
-    out = ((t00 + t01) + t10) + t11
-    return out.reshape(k, oh, ow, ch)
+    return bilinear_sample(ys[:, :, None, None], xs[:, None, :, None], fetch)
 
 
 @functools.partial(jax.jit, static_argnames=("out_hw",))

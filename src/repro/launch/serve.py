@@ -32,6 +32,7 @@ import time
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.models import transformer as tfm
 from repro.serving.server import LLMServer, Request
@@ -56,7 +57,8 @@ def serve_llm(args) -> None:
     dt = time.time() - t0
     tokens = sum(len(r.output) for r in finished)
     print(f"{cfg.name}: served {len(finished)} requests, {tokens} tokens "
-          f"in {dt:.1f}s ({tokens / dt:.1f} tok/s on CPU)")
+          f"in {dt:.1f}s wall ({tokens / dt:.1f} tok/s on "
+          f"{jax.devices()[0].platform})")
     for r in finished[:3]:
         print(f"  req {r.request_id}: {len(r.output)} tokens, "
               f"min-confidence {r.confidence:.3f}")
@@ -256,6 +258,7 @@ def main() -> None:
     ap.add_argument("--drift-window", type=int, default=8,
                     help="EWMA span (observations) of the drift detector")
     args = ap.parse_args()
+    compile_cache.configure()
     if args.per_site_learning or args.ensemble_serving:
         # both flags configure the learning plane; without it they would
         # silently do nothing

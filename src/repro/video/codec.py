@@ -33,6 +33,15 @@ class EncodedChunk(NamedTuple):
     q: int
 
 
+def _transform(spec: str, *operands) -> jax.Array:
+    """A block DCT (or its inverse) at full f32 precision.  The quantizer
+    rounds its output, so the codec's decisions must not depend on the
+    backend's default matmul precision: a TPU's one bf16 pass moves
+    coefficients across rounding boundaries and changes the decoded frames
+    by whole quantization steps."""
+    return jnp.einsum(spec, *operands, precision=jax.lax.Precision.HIGHEST)
+
+
 @functools.lru_cache(maxsize=None)
 def _dct_matrix(n: int = BLOCK) -> np.ndarray:
     k = np.arange(n)
@@ -89,14 +98,14 @@ def encode(frames: jax.Array, r: float, q: jax.Array | int) -> EncodedChunk:
 
     dct = jnp.asarray(_dct_matrix())
     blocks = _blockify(small - 0.5)
-    coef = jnp.einsum("ij,...jk,lk->...il", dct, blocks, dct)
+    coef = _transform("ij,...jk,lk->...il", dct, blocks, dct)
     step = qp_to_step(q)
     quant = jnp.round(coef / step)
 
     nbits = code_length_bits(quant)
     # decode side
     deq = quant * step
-    rec = jnp.einsum("ji,...jk,kl->...il", dct, deq, dct) + 0.5
+    rec = _transform("ji,...jk,kl->...il", dct, deq, dct) + 0.5
     rec = _unblockify(rec)[:, :h, :w]
     if r != 1.0:
         rec = jax.image.resize(rec, (t, h0, w0, c), "linear")
@@ -124,10 +133,10 @@ def encode_inter(frames: jax.Array, r: float, q) -> EncodedChunk:
     def one(prev_rec, frame):
         resid = frame - prev_rec
         blocks = _blockify(resid[None])
-        coef = jnp.einsum("ij,...jk,lk->...il", dct, blocks, dct)
+        coef = _transform("ij,...jk,lk->...il", dct, blocks, dct)
         quant = jnp.round(coef / step)
         bits = code_length_bits(quant)
-        rec_res = jnp.einsum("ji,...jk,kl->...il", dct, quant * step, dct)
+        rec_res = _transform("ji,...jk,kl->...il", dct, quant * step, dct)
         rec = jnp.clip(prev_rec + _unblockify(rec_res)[0], 0.0, 1.0)
         return rec, (rec, bits)
 
