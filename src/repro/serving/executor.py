@@ -113,13 +113,6 @@ class Executor:
                                             f"{self.name}/dev{dev}"))
         return start, done
 
-    def utilization(self, horizon: float) -> float:
-        if horizon <= 0:
-            return 0.0
-        busy = sum(r.duration for r in self.records
-                   if r.start >= self.clock - horizon)
-        return min(1.0, busy / (horizon * max(self.num_devices, 1)))
-
     def busy_fraction(self, t0: float, t1: float) -> float:
         """Fraction of the simulated window [t0, t1] this executor's device
         pool spent in service (`GraphScheduler.throughput_report` scores

@@ -61,7 +61,6 @@ import dataclasses
 import heapq
 import itertools
 import sys
-import time
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Deque, Dict, List, Optional, Tuple
@@ -83,6 +82,7 @@ from repro.serving.ingest import (ArtifactCorrupted, ArtifactStore,
 from repro.serving.monitor import Monitor
 from repro.serving.registry import Dispatcher, FunctionRegistry, ModelZoo
 from repro.serving.router import Router
+from repro.serving.spans import SpanRecorder
 from repro.serving.tenancy import TenantChunkResult
 
 STAGE_ENCODE = "fog.encode_low"
@@ -333,9 +333,11 @@ class _FlushBundle:
     numpy views.  Fields nothing reads are never downloaded — a HITL-off
     run finalizes without ever paying for ``fog_features``."""
 
-    def __init__(self, split, merged, stats: dict, field_downloads: dict):
+    def __init__(self, split, merged, stats: dict, field_downloads: dict,
+                 spans: SpanRecorder):
         self.split, self.merged = split, merged
         self._stats = stats
+        self._spans = spans
         self._field_downloads = field_downloads
         self._cache: Dict[int, np.ndarray] = {}
         self._touched = False
@@ -366,7 +368,8 @@ class _FlushBundle:
             return src                 # already materialized + swapped in
         arr = self._cache.get(id(src))
         if arr is None:
-            arr = self._cache[id(src)] = np.asarray(src)
+            with self._spans.span("vpaas.wait.result_fields"):
+                arr = self._cache[id(src)] = np.asarray(src)
             self._field_downloads[name] = (
                 self._field_downloads.get(name, 0) + 1)
             if not self._touched:
@@ -558,15 +561,25 @@ class GraphScheduler:
         # keep a global, deterministic tie-break order
         self._seq = seq_counter if seq_counter is not None \
             else itertools.count()
-        # event-loop wall accounting: step_wall_s brackets every step();
-        # model_wall_s brackets _dispatch (payload assembly + model calls),
-        # so (step - model) / finalizes is the per-chunk *scheduling*
-        # overhead — the flatness metric gated by bench_shard_scale
+        # event-loop wall accounting: step_wall_s is the vpaas.step span
+        # (every step()); model_wall_s the vpaas.dispatch span (payload
+        # assembly + model calls), so (step - model) / finalizes is the
+        # per-chunk *scheduling* overhead — the flatness metric gated by
+        # bench_shard_scale.  The span recorder splits the same wall time
+        # into host work and device waits (loop_self_wall_s, loop_wait_wall_s,
+        # dispatch_self_wall_s, prop_valid_wait_wall_s; see serving/spans.py)
         self.sched_stats = {"events": 0, "finalizes": 0,
                             "step_wall_s": 0.0, "model_wall_s": 0.0}
-        # wall-clock accounting for the jit'd detect stage (throughput lever)
+        # wall-clock accounting for the jit'd detect stage (throughput
+        # lever): wall_s is the vpaas.detect span
         self.detect_stats = {"calls": 0, "frames": 0, "padded_frames": 0,
                              "wall_s": 0.0}
+        self._spans = SpanRecorder(self.sched_stats, {
+            "vpaas.step": (self.sched_stats, "step_wall_s"),
+            "vpaas.dispatch": (self.sched_stats, "model_wall_s"),
+            "vpaas.detect": (self.detect_stats, "wall_s")})
+        self._span = self._spans.span
+        self._flush_seq = itertools.count()
         # (start, service) of every detect dispatch, held here because a
         # replica retired by scale-down takes its ExecutionRecords with it
         self._detect_windows: List[Tuple[float, float]] = []
@@ -606,8 +619,9 @@ class GraphScheduler:
         # host_syncs counts *blocking* device->host reads on the dispatch
         # path (the reads that stall the accelerator feed; the per-chunk
         # result downloads happen later, at finalize, and are counted as
-        # result_downloads)
-        self.hot_path_stats = {"flushes": 0, "host_syncs": 0,
+        # result_downloads); h2d_bytes counts the host bytes the serving
+        # path hands the device (_count_h2d)
+        self.hot_path_stats = {"flushes": 0, "host_syncs": 0, "h2d_bytes": 0,
                                "result_downloads": 0, "crops_classified": 0,
                                "crops_budget": 0, "inflight_peak": 0,
                                "ensemble_flushes": 0, "ensemble_uploads": 0,
@@ -724,31 +738,40 @@ class GraphScheduler:
         if not self._events:
             if not len(self.batcher):
                 return False
-            w0 = time.perf_counter()
-            # safety net: no event left but requests still queued — a
-            # stranded request must never be silently dropped
-            t = self.batcher.next_deadline()
-            self._run_batch(t, self.batcher.take(t))
-            self.sched_stats["events"] += 1
-            self.sched_stats["step_wall_s"] += time.perf_counter() - w0
+            with self._span("vpaas.step", action="safety_net"):
+                # safety net: no event left but requests still queued — a
+                # stranded request must never be silently dropped
+                t = self.batcher.next_deadline()
+                self._run_batch(t, self.batcher.take(t))
+                self.sched_stats["events"] += 1
             return True
-        w0 = time.perf_counter()
-        t, _, action, data = heapq.heappop(self._events)
-        if action == "ingest":
-            self._ingest(t, **data)
-        elif action == "arrive":
-            self._arrive(t, **data)
-        elif action == "flush":
-            self._flush(t)
-        elif action == "probe":
-            self._probe(t, **data)
-        elif action == "warm":
-            self._warm_check(t)
-        else:
-            self._finalize(t, data)
-        self.sched_stats["events"] += 1
-        self.sched_stats["step_wall_s"] += time.perf_counter() - w0
+        with self._span("vpaas.step", action=self._events[0][2]):
+            t, _, action, data = heapq.heappop(self._events)
+            if action == "ingest":
+                self._ingest(t, **data)
+            elif action == "arrive":
+                self._arrive(t, **data)
+            elif action == "flush":
+                self._flush(t)
+            elif action == "probe":
+                self._probe(t, **data)
+            elif action == "warm":
+                self._warm_check(t)
+            else:
+                self._finalize(t, data)
+            self.sched_stats["events"] += 1
         return True
+
+    @property
+    def span_stats(self) -> Dict[str, Dict[str, float]]:
+        """Per host span name (``vpaas.*``, serving/spans.py): count ``n``,
+        total seconds ``s`` and self seconds ``self_s``."""
+        return self._spans.stats
+
+    def _count_h2d(self, arr) -> None:
+        """Count the host bytes a ``jnp.asarray(arr)`` hands the device."""
+        if isinstance(arr, np.ndarray):
+            self.hot_path_stats["h2d_bytes"] += arr.nbytes
 
     def run_until_idle(self) -> None:
         """Drain the event queue (all submitted chunks reach finalize)."""
@@ -773,23 +796,26 @@ class GraphScheduler:
     # ------------------------------------------------------------------
     def _ingest(self, t: float, stream: StreamState, chunk,
                 learn: bool) -> None:
-        mode = "cloud"
-        if self.fault is not None:
-            mode = self.fault.heartbeat(t)
-        if mode != "cloud":
-            res = self.fallback_fn(chunk.frames)
-            self._push(t + res.latency.total, "finalize",
-                       dict(stream=stream, chunk=chunk, res=res, mode=mode,
-                            learn=learn, t0=t))
-            return
+        with self._span("vpaas.ingest", stream=stream.name):
+            mode = "cloud"
+            if self.fault is not None:
+                mode = self.fault.heartbeat(t)
+            if mode != "cloud":
+                res = self.fallback_fn(chunk.frames)
+                self._push(t + res.latency.total, "finalize",
+                           dict(stream=stream, chunk=chunk, res=res,
+                                mode=mode, learn=learn, t0=t))
+                return
 
-        proto = self.graph.protocol
-        f = chunk.frames.shape[0]
-        qc = proto.fog.encode_time(f)
-        enc, _ = stream.fog_exec.run(STAGE_ENCODE, chunk.frames, now=t,
-                                     model_time=qc)
-        self._push(t, "arrive", dict(stream=stream, chunk=chunk,
-                                     learn=learn, enc=enc, qc=qc))
+            proto = self.graph.protocol
+            f = chunk.frames.shape[0]
+            qc = proto.fog.encode_time(f)
+            with self._span("vpaas.encode.launch"):
+                self._count_h2d(chunk.frames)     # _encode's upload
+                enc, _ = stream.fog_exec.run(STAGE_ENCODE, chunk.frames,
+                                             now=t, model_time=qc)
+            self._push(t, "arrive", dict(stream=stream, chunk=chunk,
+                                         learn=learn, enc=enc, qc=qc))
 
     def _arrive(self, t: float, stream: StreamState, chunk, learn: bool,
                 enc, qc: float) -> None:
@@ -800,40 +826,43 @@ class GraphScheduler:
         other chunks' in-flight encodes instead of serializing them.  Same
         simulated times and ordering (same-time events pop in push order);
         ``float(enc.nbytes)`` stays the one unavoidable ingest-side read."""
-        wan_bytes = float(enc.nbytes)
-        wan_up = self.network.wan_time(wan_bytes, t=t)
-        arrival = t + qc + wan_up
-        frames = (enc.frames if self.hot_path == "fused"
-                  else np.asarray(enc.frames))
-        if self.store is not None:
-            # claim-check publish: the encoded frames enter the artifact
-            # store once (content-addressed — a pooled chunk re-published
-            # by any stream dedups to one payload) and the batcher queue
-            # entry carries only the reference; _dispatch resolves it at
-            # flush-assembly time and releases the claim after dispatch
-            frames = self.store.put(frames, key=self._artifact_key(chunk),
-                                    now=t)
-        req = DetectRequest(
-            frames=frames, arrival=arrival, stream=stream,
-            weight=stream.weight,
-            meta=dict(chunk=chunk, learn=learn, t0=t, qc=qc, wan_up=wan_up,
-                      wan_bytes=wan_bytes))
-        if stream.slo is not None and self.deadline_batching:
-            req.deadline = (t + stream.slo * (1.0 - stream.slo_margin)
-                            - self._downstream_est)
-        self.batcher.submit(req)
-        self._push(arrival, "flush", {})
-        nd = self.batcher.next_deadline()
-        if nd is not None and nd > arrival + 1e-12:
-            self._push(nd, "flush", {})
-        if self.warm_pool is not None:
-            # feed the per-tenant arrival forecaster and (when the policy
-            # is enabled) keep a warm-pool check event scheduled; a
-            # disabled policy observes but never schedules, leaving the
-            # event timeline untouched
-            self.warm_pool.observe(t, chunk.frames.shape[0],
-                                   self._tenant_name(stream))
-            self._schedule_warm_check(t)
+        with self._span("vpaas.arrive", stream=stream.name):
+            with self._span("vpaas.wait.encode_nbytes"):
+                wan_bytes = float(enc.nbytes)
+            wan_up = self.network.wan_time(wan_bytes, t=t)
+            arrival = t + qc + wan_up
+            frames = (enc.frames if self.hot_path == "fused"
+                      else np.asarray(enc.frames))
+            if self.store is not None:
+                # claim-check publish: the encoded frames enter the
+                # artifact store once (content-addressed — a pooled chunk
+                # re-published by any stream dedups to one payload) and the
+                # batcher queue entry carries only the reference; _dispatch
+                # resolves it at flush-assembly time and releases the claim
+                # after dispatch
+                frames = self.store.put(
+                    frames, key=self._artifact_key(chunk), now=t)
+            req = DetectRequest(
+                frames=frames, arrival=arrival, stream=stream,
+                weight=stream.weight,
+                meta=dict(chunk=chunk, learn=learn, t0=t, qc=qc,
+                          wan_up=wan_up, wan_bytes=wan_bytes))
+            if stream.slo is not None and self.deadline_batching:
+                req.deadline = (t + stream.slo * (1.0 - stream.slo_margin)
+                                - self._downstream_est)
+            self.batcher.submit(req)
+            self._push(arrival, "flush", {})
+            nd = self.batcher.next_deadline()
+            if nd is not None and nd > arrival + 1e-12:
+                self._push(nd, "flush", {})
+            if self.warm_pool is not None:
+                # feed the per-tenant arrival forecaster and (when the
+                # policy is enabled) keep a warm-pool check event scheduled;
+                # a disabled policy observes but never schedules, leaving
+                # the event timeline untouched
+                self.warm_pool.observe(t, chunk.frames.shape[0],
+                                       self._tenant_name(stream))
+                self._schedule_warm_check(t)
 
     def _artifact_key(self, chunk) -> str:
         """Content address of a chunk's encoded payload.
@@ -858,14 +887,16 @@ class GraphScheduler:
         return key
 
     def _flush(self, t: float) -> None:
-        while self.batcher.ready(t):
-            self._run_batch(t, self.batcher.take(t))
-        if len(self.batcher):
-            # deadline-driven flushes move earlier as the queue grows (the
-            # estimated service time rises); keep an event at the horizon
-            nd = self.batcher.next_deadline()
-            if nd is not None and nd > t + 1e-12:
-                self._push(nd, "flush", {})
+        with self._span("vpaas.flush"):
+            while self.batcher.ready(t):
+                self._run_batch(t, self.batcher.take(t))
+            if len(self.batcher):
+                # deadline-driven flushes move earlier as the queue grows
+                # (the estimated service time rises); keep an event at the
+                # horizon
+                nd = self.batcher.next_deadline()
+                if nd is not None and nd > t + 1e-12:
+                    self._push(nd, "flush", {})
 
     # ------------------------------------------------------------------
     def _run_batch(self, t: float, reqs: List[DetectRequest]) -> None:
@@ -955,126 +986,137 @@ class GraphScheduler:
 
     def _dispatch(self, t: float, reqs: List[DetectRequest]) -> None:
         proto = self.graph.protocol
-        m0 = time.perf_counter()
-        # artifact-corruption faults fire at flush assembly: flip stored
-        # payload bytes now, so the integrity-checked resolve below detects
-        # and repairs every one of them before it can reach the detector
-        if self.store is not None and self.fault is not None:
-            due_fn = getattr(self.fault, "due_corruptions", None)
-            if due_fn is not None:
-                keys, seen = [], set()
+        flush = next(self._flush_seq)
+        with self._span("vpaas.dispatch", flush=flush):
+            # artifact-corruption faults fire at flush assembly: flip
+            # stored payload bytes now, so the integrity-checked resolve
+            # below detects and repairs every one of them before it can
+            # reach the detector
+            if self.store is not None and self.fault is not None:
+                due_fn = getattr(self.fault, "due_corruptions", None)
+                if due_fn is not None:
+                    keys, seen = [], set()
+                    for r in reqs:
+                        if (isinstance(r.frames, ClaimCheck)
+                                and r.frames.key not in seen):
+                            seen.add(r.frames.key)
+                            keys.append(r.frames.key)
+                    for i in range(due_fn(t, len(keys))):
+                        self.store.corrupt(keys[i])
+            # pick a replica; health-check it against the fault schedule
+            # first (the schedule is keyed by the replica's stable uid, not
+            # its pool position — positions shift when the autoscaler
+            # resizes the pool)
+            while True:
+                idx = self.router.pick()
+                if idx is None:
+                    self._fallback_batch(t, reqs)
+                    return
+                uid = self.router.replicas[idx].uid
+                if (self.fault is not None
+                        and self.fault.replica_down(uid, t)):
+                    self.router.mark_unhealthy(idx, now=t)
+                    self.fault.note_replica_failure(uid, t, requeued=0)
+                    self._schedule_probe(uid, t)
+                    continue
+                break
+            fused = self.hot_path == "fused"
+            # claim-check resolve: flush assembly is the ONE place payloads
+            # are pulled from the store.  A single-request flush passes the
+            # stored array object straight through pack_frames_device,
+            # preserving the zero-copy identity shortcut.
+            with self._span("vpaas.dispatch.pack", flush=flush):
+                if self.store is not None:
+                    payloads = [self._resolve_payload(r, t) for r in reqs]
+                else:
+                    payloads = [r.frames for r in reqs]
+                if fused:
+                    batch, slices, pad = pack_frames_device(
+                        payloads, buckets=self.batcher.pad_buckets)
+                else:
+                    batch, slices, pad = pack_frames(
+                        [np.asarray(p) for p in payloads],
+                        buckets=self.batcher.pad_buckets)
+            n_frames = batch.shape[0]
+            svc = proto.cloud.detect_time(n_frames)
+            rep = self.router.replicas[idx]
+            est_start = max(t, min(rep.executor.busy_until))
+            if self.fault is not None:
+                # straggler windows stretch the true service time;
+                # flap/death windows interrupt it.  Both are keyed on where
+                # the service actually sits on the replica's device
+                # horizon, not on `t`.
+                mult = self.fault.service_multiplier(uid, est_start)
+                svc_eff = svc * mult if mult != 1.0 else svc
+                fail_t = self.fault.fail_time_in(uid, est_start,
+                                                 est_start + svc_eff)
+            else:
+                svc_eff, fail_t = svc, None
+            if fail_t is not None:
+                # the replica dies (or flaps out) while this sub-batch is
+                # in service: its work is lost, the outage is detected at
+                # the failure time, and the chunks re-queue to surviving
+                # replicas (arrival and fair-queueing position preserved —
+                # nothing is dropped).  Their claims were not released, so
+                # the re-flush resolves the same stored payloads again.  A
+                # transient flap additionally starts a health-probe chain so
+                # the replica re-admits once its window closes.
+                self.router.mark_unhealthy(idx, now=fail_t)
+                self.fault.note_replica_failure(uid, fail_t,
+                                                requeued=len(reqs))
+                self.chaos_stats["requeues"] += len(reqs)
+                self._schedule_probe(uid, fail_t)
                 for r in reqs:
-                    if (isinstance(r.frames, ClaimCheck)
-                            and r.frames.key not in seen):
-                        seen.add(r.frames.key)
-                        keys.append(r.frames.key)
-                for i in range(due_fn(t, len(keys))):
-                    self.store.corrupt(keys[i])
-        # pick a replica; health-check it against the fault schedule first
-        # (the schedule is keyed by the replica's stable uid, not its pool
-        # position — positions shift when the autoscaler resizes the pool)
-        while True:
-            idx = self.router.pick()
-            if idx is None:
-                self._fallback_batch(t, reqs)
+                    r.not_before = fail_t
+                    r.retries += 1
+                    self.batcher.submit(r)
+                self._push(fail_t, "flush", {})
                 return
-            uid = self.router.replicas[idx].uid
-            if self.fault is not None and self.fault.replica_down(uid, t):
-                self.router.mark_unhealthy(idx, now=t)
-                self.fault.note_replica_failure(uid, t, requeued=0)
-                self._schedule_probe(uid, t)
-                continue
-            break
-        fused = self.hot_path == "fused"
-        # claim-check resolve: flush assembly is the ONE place payloads are
-        # pulled from the store.  A single-request flush passes the stored
-        # array object straight through pack_frames_device, preserving the
-        # zero-copy identity shortcut.
-        if self.store is not None:
-            payloads = [self._resolve_payload(r, t) for r in reqs]
-        else:
-            payloads = [r.frames for r in reqs]
-        if fused:
-            batch, slices, pad = pack_frames_device(
-                payloads, buckets=self.batcher.pad_buckets)
-        else:
-            batch, slices, pad = pack_frames(
-                [np.asarray(p) for p in payloads],
-                buckets=self.batcher.pad_buckets)
-        n_frames = batch.shape[0]
-        svc = proto.cloud.detect_time(n_frames)
-        rep = self.router.replicas[idx]
-        est_start = max(t, min(rep.executor.busy_until))
-        if self.fault is not None:
-            # straggler windows stretch the true service time; flap/death
-            # windows interrupt it.  Both are keyed on where the service
-            # actually sits on the replica's device horizon, not on `t`.
-            mult = self.fault.service_multiplier(uid, est_start)
-            svc_eff = svc * mult if mult != 1.0 else svc
-            fail_t = self.fault.fail_time_in(uid, est_start,
-                                             est_start + svc_eff)
-        else:
-            svc_eff, fail_t = svc, None
-        if fail_t is not None:
-            # the replica dies (or flaps out) while this sub-batch is in
-            # service: its work is lost, the outage is detected at the
-            # failure time, and the chunks re-queue to surviving replicas
-            # (arrival and fair-queueing position preserved — nothing is
-            # dropped).  Their claims were not released, so the re-flush
-            # resolves the same stored payloads again.  A transient flap
-            # additionally starts a health-probe chain so the replica
-            # re-admits once its window closes.
-            self.router.mark_unhealthy(idx, now=fail_t)
-            self.fault.note_replica_failure(uid, fail_t,
-                                            requeued=len(reqs))
-            self.chaos_stats["requeues"] += len(reqs)
-            self._schedule_probe(uid, fail_t)
-            for r in reqs:
-                r.not_before = fail_t
-                r.retries += 1
-                self.batcher.submit(r)
-            self._push(fail_t, "flush", {})
-            return
-        if self.store is not None:
-            # dispatch is committed: the batch owns the frame data now, so
-            # the claims drop and idle payloads age toward TTL eviction
-            for r in reqs:
-                self.store.release(r.frames, now=t)
-            self.store.sweep(t)
-        # real queue depth (frames still waiting / in flight to the cloud)
-        queue_depth = self.batcher.pending_frames
-        if self.cost_model is not None:
-            self.cost_model.observe_pool(t, self.router.healthy_count())
-        # per-dispatch timeout = the flush's SLO slack (tightest pending
-        # detect deadline), and the hedge decision: a primary whose
-        # service-rate EWMA says this sub-batch will both straggle (beyond
-        # the slack threshold) and miss that deadline gets a speculative
-        # duplicate on the best alternate replica, first-result-wins
-        deadline = min((r.deadline for r in reqs if r.deadline is not None),
-                       default=None)
-        timeout = max(0.0, deadline - t) if deadline is not None else None
-        hedge = None
-        if (self.hedging and self.fault is not None
-                and deadline is not None and rep.rate_ewma is not None):
-            est_svc = rep.rate_ewma * n_frames
-            if (est_svc > svc * (1.0 + self.hedge_slack)
-                    and est_start + est_svc > deadline):
-                hedge = self._pick_hedge(t, idx, svc, n_frames,
-                                         est_start + est_svc)
-        self.hot_path_stats["flushes"] += 1
-        if fused:
-            self._dispatch_fused(t, reqs, slices, pad, batch, svc_eff, idx,
-                                 queue_depth, timeout, hedge)
-        else:
-            self._dispatch_sync(t, reqs, slices, pad, batch, svc_eff, idx,
-                                queue_depth, timeout, hedge)
-        # observed per-frame service rate feeds the next hedge decision;
-        # one-dispatch lag is the realistic detector dynamic (a straggler
-        # is spotted by its first slow completion, then hedged around)
-        obs = svc_eff / max(n_frames, 1)
-        rep.rate_ewma = (obs if rep.rate_ewma is None
-                         else 0.5 * rep.rate_ewma + 0.5 * obs)
-        self.sched_stats["model_wall_s"] += time.perf_counter() - m0
+            if self.store is not None:
+                # dispatch is committed: the batch owns the frame data now,
+                # so the claims drop and idle payloads age toward TTL
+                # eviction
+                for r in reqs:
+                    self.store.release(r.frames, now=t)
+                self.store.sweep(t)
+            # real queue depth (frames still waiting / in flight to the
+            # cloud)
+            queue_depth = self.batcher.pending_frames
+            if self.cost_model is not None:
+                self.cost_model.observe_pool(t, self.router.healthy_count())
+            # per-dispatch timeout = the flush's SLO slack (tightest pending
+            # detect deadline), and the hedge decision: a primary whose
+            # service-rate EWMA says this sub-batch will both straggle
+            # (beyond the slack threshold) and miss that deadline gets a
+            # speculative duplicate on the best alternate replica,
+            # first-result-wins
+            deadline = min((r.deadline for r in reqs
+                            if r.deadline is not None), default=None)
+            timeout = (max(0.0, deadline - t) if deadline is not None
+                       else None)
+            hedge = None
+            if (self.hedging and self.fault is not None
+                    and deadline is not None and rep.rate_ewma is not None):
+                est_svc = rep.rate_ewma * n_frames
+                if (est_svc > svc * (1.0 + self.hedge_slack)
+                        and est_start + est_svc > deadline):
+                    hedge = self._pick_hedge(t, idx, svc, n_frames,
+                                             est_start + est_svc)
+            self.hot_path_stats["flushes"] += 1
+            if fused:
+                self._dispatch_fused(t, reqs, slices, pad, batch, svc_eff,
+                                     idx, queue_depth, timeout, hedge,
+                                     flush=flush)
+            else:
+                self._dispatch_sync(t, reqs, slices, pad, batch, svc_eff,
+                                    idx, queue_depth, timeout, hedge,
+                                    flush=flush)
+            # observed per-frame service rate feeds the next hedge decision;
+            # one-dispatch lag is the realistic detector dynamic (a straggler
+            # is spotted by its first slow completion, then hedged around)
+            obs = svc_eff / max(n_frames, 1)
+            rep.rate_ewma = (obs if rep.rate_ewma is None
+                             else 0.5 * rep.rate_ewma + 0.5 * obs)
 
     def _resolve_payload(self, req: DetectRequest, t: float):
         """Resolve one request's claim; repair a corrupted payload.
@@ -1089,6 +1131,7 @@ class GraphScheduler:
         try:
             return self.store.get(req.frames)
         except ArtifactCorrupted:
+            self._count_h2d(req.meta["chunk"].frames)
             enc = self.graph._encode(req.meta["chunk"].frames)
             fresh = (enc.frames if self.hot_path == "fused"
                      else np.asarray(enc.frames))
@@ -1246,22 +1289,22 @@ class GraphScheduler:
     def _dispatch_sync(self, t: float, reqs: List[DetectRequest], slices,
                        pad: int, batch, svc: float, idx: int,
                        queue_depth: int, timeout: Optional[float] = None,
-                       hedge=None) -> None:
+                       hedge=None, *, flush: int) -> None:
         """Pre-fusion baseline: blocking detect, one ``split_uncertain``
         jit call plus two scalar device syncs per chunk, full-budget
         classify, immediate result materialization."""
         proto = self.graph.protocol
         n_frames = batch.shape[0]
-        w0 = time.perf_counter()
-        det, done, svc_w, h_billed = self._route_detect(
-            STAGE_DETECT, (jnp.asarray(batch),), t=t, svc=svc, idx=idx,
-            queue_depth=queue_depth, timeout=timeout, hedge=hedge)
-        jax.block_until_ready(det)
-        self.hot_path_stats["host_syncs"] += 1
-        self.detect_stats["calls"] += 1
-        self.detect_stats["frames"] += n_frames - pad
-        self.detect_stats["padded_frames"] += pad
-        self.detect_stats["wall_s"] += time.perf_counter() - w0
+        with self._span("vpaas.detect", flush=flush):
+            det, done, svc_w, h_billed = self._route_detect(
+                STAGE_DETECT, (jnp.asarray(batch),), t=t, svc=svc, idx=idx,
+                queue_depth=queue_depth, timeout=timeout, hedge=hedge)
+            with self._span("vpaas.wait.detect", flush=flush):
+                jax.block_until_ready(det)
+            self.hot_path_stats["host_syncs"] += 1
+            self.detect_stats["calls"] += 1
+            self.detect_stats["frames"] += n_frames - pad
+            self.detect_stats["padded_frames"] += pad
         start = done - svc_w
 
         for req, sl in zip(reqs, slices):
@@ -1282,8 +1325,10 @@ class GraphScheduler:
                                else pcfg_req.theta_loc))
             split, coord_bytes = protocol_mod.split_uncertain(pcfg_req,
                                                               det_i)
-            wan_down = self.network.wan_time(float(coord_bytes), t=done)
-            n_crops = int(np.sum(np.asarray(split.prop_valid)))
+            with self._span("vpaas.wait.prop_valid", flush=flush):
+                coord_bytes = float(coord_bytes)
+                n_crops = int(np.sum(np.asarray(split.prop_valid)))
+            wan_down = self.network.wan_time(coord_bytes, t=done)
             self.hot_path_stats["host_syncs"] += 2   # the two scalar reads
             clf_time = proto.fog.classify_time(max(n_crops, 1))
             obs = wan_down + clf_time
@@ -1330,10 +1375,11 @@ class GraphScheduler:
                 cloud_inference=svc_w,
                 fog_inference=clf_time,
                 queue_wait=max(0.0, start - req.arrival) + fog_wait)
-            res = protocol_mod.assemble_result(
-                split, merged, wan_bytes=req.meta["wan_bytes"],
-                coord_bytes=float(coord_bytes),
-                cloud_frames=req.frames.shape[0], latency=lat)
+            with self._span("vpaas.wait.result_fields", flush=flush):
+                res = protocol_mod.assemble_result(
+                    split, merged, wan_bytes=req.meta["wan_bytes"],
+                    coord_bytes=coord_bytes,
+                    cloud_frames=req.frames.shape[0], latency=lat)
             self.hot_path_stats["host_syncs"] += 1   # eager materialization
             self._push(req.meta["t0"] + lat.total, "finalize",
                        dict(stream=stream, chunk=chunk, res=res,
@@ -1343,180 +1389,195 @@ class GraphScheduler:
     def _dispatch_fused(self, t: float, reqs: List[DetectRequest], slices,
                         pad: int, batch, svc: float, idx: int,
                         queue_depth: int, timeout: Optional[float] = None,
-                        hedge=None) -> None:
+                        hedge=None, *, flush: int) -> None:
         """Device-resident hot path: one fused detect+split dispatch, ONE
         blocking host read (the validity mask) per flush, one compacted
         cross-stream classify dispatch, and per-chunk results left as
         device futures drained at their finalize events."""
         proto = self.graph.protocol
         n_frames = batch.shape[0]
-        w0 = time.perf_counter()
-        dyn = any(r.stream.theta_cls is not None
-                  or r.stream.theta_loc is not None for r in reqs)
-        if dyn:
-            # per-site thresholds in play: per-frame theta vectors ride
-            # into the dynamic fused stage as traced args (thetas only
-            # enter elementwise comparisons, so tracing them is exact);
-            # detector pad rows keep the global defaults
-            tc = np.full(n_frames, proto.pcfg.theta_cls, np.float32)
-            tl = np.full(n_frames, proto.pcfg.theta_loc, np.float32)
-            for r, sl in zip(reqs, slices):
-                if r.stream.theta_cls is not None:
-                    tc[sl] = r.stream.theta_cls
-                if r.stream.theta_loc is not None:
-                    tl[sl] = r.stream.theta_loc
-            split, done, svc_w, h_billed = self._route_detect(
-                STAGE_DETECT_SPLIT_DYN,
-                (batch, jnp.asarray(tc), jnp.asarray(tl)), t=t, svc=svc,
-                idx=idx, queue_depth=queue_depth, timeout=timeout,
-                hedge=hedge)
-        else:
-            # donate the packed batch only when it is the dispatch-owned
-            # multi-request concat; a single-request flush passes the
-            # encode-output / store-held array through untouched
-            stage = (STAGE_DETECT_SPLIT_DON
-                     if self.donate_detect and len(reqs) > 1
-                     else STAGE_DETECT_SPLIT)
-            split, done, svc_w, h_billed = self._route_detect(
-                stage, (batch,), t=t, svc=svc, idx=idx,
-                queue_depth=queue_depth, timeout=timeout, hedge=hedge)
-        # THE flush's single blocking device->host read: per-chunk coord
-        # bytes, crop counts, and the compaction gather plan are all
-        # derived from this one (F, N) bool mask on the host
-        pv = np.asarray(split.prop_valid)
-        self.hot_path_stats["host_syncs"] += 1
-        self.detect_stats["calls"] += 1
-        self.detect_stats["frames"] += n_frames - pad
-        self.detect_stats["padded_frames"] += pad
-        self.detect_stats["wall_s"] += time.perf_counter() - w0
+        with self._span("vpaas.detect", flush=flush):
+            dyn = any(r.stream.theta_cls is not None
+                      or r.stream.theta_loc is not None for r in reqs)
+            if dyn:
+                # per-site thresholds in play: per-frame theta vectors ride
+                # into the dynamic fused stage as traced args (thetas only
+                # enter elementwise comparisons, so tracing them is exact);
+                # detector pad rows keep the global defaults
+                tc = np.full(n_frames, proto.pcfg.theta_cls, np.float32)
+                tl = np.full(n_frames, proto.pcfg.theta_loc, np.float32)
+                for r, sl in zip(reqs, slices):
+                    if r.stream.theta_cls is not None:
+                        tc[sl] = r.stream.theta_cls
+                    if r.stream.theta_loc is not None:
+                        tl[sl] = r.stream.theta_loc
+                self._count_h2d(tc)
+                self._count_h2d(tl)
+                split, done, svc_w, h_billed = self._route_detect(
+                    STAGE_DETECT_SPLIT_DYN,
+                    (batch, jnp.asarray(tc), jnp.asarray(tl)), t=t, svc=svc,
+                    idx=idx, queue_depth=queue_depth, timeout=timeout,
+                    hedge=hedge)
+            else:
+                # donate the packed batch only when it is the dispatch-owned
+                # multi-request concat; a single-request flush passes the
+                # encode-output / store-held array through untouched
+                stage = (STAGE_DETECT_SPLIT_DON
+                         if self.donate_detect and len(reqs) > 1
+                         else STAGE_DETECT_SPLIT)
+                split, done, svc_w, h_billed = self._route_detect(
+                    stage, (batch,), t=t, svc=svc, idx=idx,
+                    queue_depth=queue_depth, timeout=timeout, hedge=hedge)
+            # THE flush's single blocking device->host read: per-chunk coord
+            # bytes, crop counts, and the compaction gather plan are all
+            # derived from this one (F, N) bool mask on the host
+            with self._span("vpaas.wait.prop_valid", flush=flush):
+                pv = np.asarray(split.prop_valid)
+            self.hot_path_stats["host_syncs"] += 1
+            self.detect_stats["calls"] += 1
+            self.detect_stats["frames"] += n_frames - pad
+            self.detect_stats["padded_frames"] += pad
         start = done - svc_w
 
-        # detector padding rows carry no chunk: drop them before building
-        # the gather plan (a zero-frame can still excite a random detector)
-        f_real = n_frames - pad
-        pv = pv[:f_real]
-        counts = pv.sum(axis=1)
-        split_real = (reg.RegionSplit(*(v[:f_real] for v in split))
-                      if pad else split)
-        fidx, ridx, n_valid, bucket = reg.compaction_indices(
-            pv, self.crop_buckets)
-        self.hot_path_stats["crops_classified"] += bucket
-        self.hot_path_stats["crops_budget"] += int(pv.size)
+        with self._span("vpaas.dispatch.plan", flush=flush):
+            # detector padding rows carry no chunk: drop them before
+            # building the gather plan (a zero-frame can still excite a
+            # random detector)
+            f_real = n_frames - pad
+            pv = pv[:f_real]
+            counts = pv.sum(axis=1)
+            split_real = (reg.RegionSplit(*(v[:f_real] for v in split))
+                          if pad else split)
+            fidx, ridx, n_valid, bucket = reg.compaction_indices(
+                pv, self.crop_buckets)
+            self.hot_path_stats["crops_classified"] += bucket
+            self.hot_path_stats["crops_budget"] += int(pv.size)
+            # the distinct per-stream readouts, one group each
+            w_group: Dict[int, int] = {}
+            group_streams: List[StreamState] = []
+            req_w = np.empty(len(reqs), np.int32)
+            frame_req = np.empty(f_real, np.int32)
+            use_ens = any(r.stream.snaps is not None for r in reqs)
+            for qi, (r, sl) in enumerate(zip(reqs, slices)):
+                key = (id(r.stream.snaps) if r.stream.snaps is not None
+                       else id(r.stream.W))
+                if key not in w_group:
+                    w_group[key] = len(group_streams)
+                    group_streams.append(r.stream)
+                req_w[qi] = w_group[key]
+                frame_req[sl] = qi
+            # one (3, B) index upload: (fidx, ridx, widx) rows
+            idxs = np.zeros((3, bucket), np.int32)
+            idxs[0] = fidx
+            idxs[1] = ridx
+            if n_valid:
+                idxs[2, :n_valid] = req_w[frame_req[fidx[:n_valid]]]
+            clf_time = proto.fog.classify_time(max(n_valid, 1))
 
-        # pack the cached HQ frames: host-side video sources, so concat on
-        # the host and pay ONE upload per flush (not one device_put per
-        # chunk), and stack the distinct per-stream readouts
-        if len(reqs) == 1:
-            hq_batch = jnp.asarray(reqs[0].meta["chunk"].frames)
-        else:
-            hq_batch = jnp.asarray(np.concatenate(
-                [np.asarray(r.meta["chunk"].frames) for r in reqs], axis=0))
-        w_group: Dict[int, int] = {}
-        group_streams: List[StreamState] = []
-        req_w = np.empty(len(reqs), np.int32)
-        frame_req = np.empty(f_real, np.int32)
-        use_ens = any(r.stream.snaps is not None for r in reqs)
-        for qi, (r, sl) in enumerate(zip(reqs, slices)):
-            key = (id(r.stream.snaps) if r.stream.snaps is not None
-                   else id(r.stream.W))
-            if key not in w_group:
-                w_group[key] = len(group_streams)
-                group_streams.append(r.stream)
-            req_w[qi] = w_group[key]
-            frame_req[sl] = qi
-        # one (3, B) index upload: (fidx, ridx, widx) rows
-        idxs = np.zeros((3, bucket), np.int32)
-        idxs[0] = fidx
-        idxs[1] = ridx
-        if n_valid:
-            idxs[2, :n_valid] = req_w[frame_req[fidx[:n_valid]]]
+        with self._span("vpaas.dispatch.hq_upload", flush=flush):
+            # pack the cached HQ frames: host-side video sources, so concat
+            # on the host and pay ONE upload per flush (not one device_put
+            # per chunk)
+            if len(reqs) == 1:
+                hq_host = reqs[0].meta["chunk"].frames
+            else:
+                hq_host = np.concatenate(
+                    [np.asarray(r.meta["chunk"].frames) for r in reqs],
+                    axis=0)
+            self._count_h2d(hq_host)
+            self._count_h2d(idxs)
+            hq_batch = jnp.asarray(hq_host)
+            idxs_dev = jnp.asarray(idxs)
 
-        clf_time = proto.fog.classify_time(max(n_valid, 1))
-        if use_ens:
-            # Eq. 9 ensemble serving: widx picks a per-stream snapshot
-            # lineage; plain single-readout streams ride along as the
-            # zero-padded degenerate lineage [W] / omega=[1.0] (bitwise-
-            # identical scores, see classify_compacted_ensemble)
-            snaps_dev, omegas_dev = self._ensemble_stack(group_streams)
-            self.hot_path_stats["ensemble_flushes"] += 1
-            merged, _ = self.fog_batch_exec.run(
-                STAGE_CLASSIFY_ENS_BATCH, hq_batch, split_real, snaps_dev,
-                omegas_dev, jnp.asarray(idxs), now=done,
-                model_time=clf_time)
-        else:
-            ws_list = [s.W_device() for s in group_streams]
-            Ws = (ws_list[0][None] if len(ws_list) == 1
-                  else jnp.stack(ws_list))
-            merged, _ = self.fog_batch_exec.run(
-                STAGE_CLASSIFY_BATCH, hq_batch, split_real, Ws,
-                jnp.asarray(idxs), now=done, model_time=clf_time)
+        with self._span("vpaas.classify.launch", flush=flush):
+            if use_ens:
+                # Eq. 9 ensemble serving: widx picks a per-stream snapshot
+                # lineage; plain single-readout streams ride along as the
+                # zero-padded degenerate lineage [W] / omega=[1.0] (bitwise-
+                # identical scores, see classify_compacted_ensemble)
+                snaps_dev, omegas_dev = self._ensemble_stack(group_streams)
+                self.hot_path_stats["ensemble_flushes"] += 1
+                merged, _ = self.fog_batch_exec.run(
+                    STAGE_CLASSIFY_ENS_BATCH, hq_batch, split_real,
+                    snaps_dev, omegas_dev, idxs_dev, now=done,
+                    model_time=clf_time)
+            else:
+                ws_list = [s.W_device() for s in group_streams]
+                Ws = (ws_list[0][None] if len(ws_list) == 1
+                      else jnp.stack(ws_list))
+                merged, _ = self.fog_batch_exec.run(
+                    STAGE_CLASSIFY_BATCH, hq_batch, split_real, Ws,
+                    idxs_dev, now=done, model_time=clf_time)
 
-        # the whole flush's results travel as ONE device-side bundle whose
-        # fields materialize lazily: a consumer's first touch of a field
-        # downloads that buffer once for the whole flush and every chunk
-        # slices numpy views — fields nothing reads are never downloaded
-        bundle = _FlushBundle(split_real, merged, self.hot_path_stats,
-                              self.field_downloads)
-        bundle.pending = len(reqs)
-        self._bundles.append(bundle)
-        hps = self.hot_path_stats
-        hps["bundle_bytes"] += bundle.device_bytes
-        hps["bundle_bytes_peak"] = max(hps["bundle_bytes_peak"],
-                                       hps["bundle_bytes"])
-        hps["bundles_retained_peak"] = max(hps["bundles_retained_peak"],
-                                           len(self._bundles))
-        # residency time series (sim clock): the steady-state bench asserts
-        # this stays flat under bounded retention
-        self.monitor.record("bundle_bytes", float(hps["bundle_bytes"]), t)
-        for req, sl in zip(reqs, slices):
-            n_crops = int(counts[sl].sum())
-            coord_bytes = 9.0 * n_crops
-            wan_down = self.network.wan_time(coord_bytes, t=done)
-            clf_time = proto.fog.classify_time(max(n_crops, 1))
-            obs = wan_down + clf_time
-            self._downstream_est = (obs if obs > self._downstream_est
-                                    else 0.9 * self._downstream_est
-                                    + 0.1 * obs)
-            stream = req.stream
-            chunk = req.meta["chunk"]
-            # the stream's share of the batched classify: pure accounting
-            # on its own fog node's clock (the compute already ran batched)
-            _, done_c = stream.fog_exec.run(STAGE_CLASSIFY_VIEW, sl,
-                                            now=done + wan_down,
-                                            model_time=clf_time)
-            fog_wait = (max(0.0, done_c - clf_time - (done + wan_down))
-                        if self.fog_queueing else 0.0)
-            if self.cost_model is not None:
-                f = req.frames.shape[0]
-                tname = self._tenant_name(stream)
-                self.cost_model.charge_cloud(
-                    tname, frames=f, invocations=f,
-                    busy_s=svc * f / max(f_real, 1), t=t)
-                if h_billed is not None:
-                    # a hedge is a real invocation: its duplicate device
-                    # time lands in the tenant's ledger either way the
-                    # race resolves
-                    self.cost_model.charge_hedge(
-                        tname, invocations=f,
-                        busy_s=h_billed * f / max(f_real, 1), t=t)
-                self.cost_model.charge_fog(tname, clf_time, t)
-            lat = LatencyBreakdown(
-                quality_control=req.meta["qc"],
-                transmission=req.meta["wan_up"] + wan_down,
-                cloud_inference=svc_w,
-                fog_inference=clf_time,
-                queue_wait=max(0.0, start - req.arrival) + fog_wait)
-            res = LazyChunkResult(
-                bundle, sl, wan_bytes=req.meta["wan_bytes"],
-                coord_bytes=coord_bytes,
-                cloud_frames=req.frames.shape[0], latency=lat)
-            self._inflight.append(res)
-            self.hot_path_stats["inflight_peak"] = max(
-                self.hot_path_stats["inflight_peak"], len(self._inflight))
-            self._push(req.meta["t0"] + lat.total, "finalize",
-                       dict(stream=stream, chunk=chunk, res=res,
-                            inflight=True, mode="cloud",
-                            learn=req.meta["learn"], t0=req.meta["t0"]))
+        with self._span("vpaas.dispatch.results", flush=flush):
+            # the whole flush's results travel as ONE device-side bundle
+            # whose fields materialize lazily: a consumer's first touch of a
+            # field downloads that buffer once for the whole flush and every
+            # chunk slices numpy views — fields nothing reads are never
+            # downloaded
+            bundle = _FlushBundle(split_real, merged, self.hot_path_stats,
+                                  self.field_downloads, self._spans)
+            bundle.pending = len(reqs)
+            self._bundles.append(bundle)
+            hps = self.hot_path_stats
+            hps["bundle_bytes"] += bundle.device_bytes
+            hps["bundle_bytes_peak"] = max(hps["bundle_bytes_peak"],
+                                           hps["bundle_bytes"])
+            hps["bundles_retained_peak"] = max(hps["bundles_retained_peak"],
+                                               len(self._bundles))
+            # residency time series (sim clock): the steady-state bench
+            # asserts this stays flat under bounded retention
+            self.monitor.record("bundle_bytes", float(hps["bundle_bytes"]), t)
+            for req, sl in zip(reqs, slices):
+                n_crops = int(counts[sl].sum())
+                coord_bytes = 9.0 * n_crops
+                wan_down = self.network.wan_time(coord_bytes, t=done)
+                clf_time = proto.fog.classify_time(max(n_crops, 1))
+                obs = wan_down + clf_time
+                self._downstream_est = (obs if obs > self._downstream_est
+                                        else 0.9 * self._downstream_est
+                                        + 0.1 * obs)
+                stream = req.stream
+                chunk = req.meta["chunk"]
+                # the stream's share of the batched classify: pure
+                # accounting on its own fog node's clock (the compute
+                # already ran batched)
+                _, done_c = stream.fog_exec.run(STAGE_CLASSIFY_VIEW, sl,
+                                                now=done + wan_down,
+                                                model_time=clf_time)
+                fog_wait = (max(0.0, done_c - clf_time - (done + wan_down))
+                            if self.fog_queueing else 0.0)
+                if self.cost_model is not None:
+                    f = req.frames.shape[0]
+                    tname = self._tenant_name(stream)
+                    self.cost_model.charge_cloud(
+                        tname, frames=f, invocations=f,
+                        busy_s=svc * f / max(f_real, 1), t=t)
+                    if h_billed is not None:
+                        # a hedge is a real invocation: its duplicate device
+                        # time lands in the tenant's ledger either way the
+                        # race resolves
+                        self.cost_model.charge_hedge(
+                            tname, invocations=f,
+                            busy_s=h_billed * f / max(f_real, 1), t=t)
+                    self.cost_model.charge_fog(tname, clf_time, t)
+                lat = LatencyBreakdown(
+                    quality_control=req.meta["qc"],
+                    transmission=req.meta["wan_up"] + wan_down,
+                    cloud_inference=svc_w,
+                    fog_inference=clf_time,
+                    queue_wait=max(0.0, start - req.arrival) + fog_wait)
+                res = LazyChunkResult(
+                    bundle, sl, wan_bytes=req.meta["wan_bytes"],
+                    coord_bytes=coord_bytes,
+                    cloud_frames=req.frames.shape[0], latency=lat)
+                self._inflight.append(res)
+                self.hot_path_stats["inflight_peak"] = max(
+                    self.hot_path_stats["inflight_peak"], len(self._inflight))
+                self._push(req.meta["t0"] + lat.total, "finalize",
+                           dict(stream=stream, chunk=chunk, res=res,
+                                inflight=True, mode="cloud",
+                                learn=req.meta["learn"], t0=req.meta["t0"]))
 
     def _dispatch_tenant(self, t: float, reqs: List[DetectRequest],
                          pipe) -> None:
@@ -1530,154 +1591,164 @@ class GraphScheduler:
         clean.  Custom pipelines do not participate in the fault-schedule
         fallback (that path re-encodes for the fog *detector*, which a
         non-detection graph doesn't have)."""
-        m0 = time.perf_counter()
-        idx = self.router.pick()
-        if idx is None:
-            # terminal path (tenant pipelines have no fog fallback): the
-            # claims must not outlive the flush that dies here
+        flush = next(self._flush_seq)
+        with self._span("vpaas.dispatch", flush=flush):
+            idx = self.router.pick()
+            if idx is None:
+                # terminal path (tenant pipelines have no fog fallback): the
+                # claims must not outlive the flush that dies here
+                if self.store is not None:
+                    for r in reqs:
+                        if isinstance(r.frames, ClaimCheck):
+                            self.store.release(r.frames, now=t)
+                raise RuntimeError(
+                    f"no healthy replicas for tenant pipeline {pipe.name!r}")
+            with self._span("vpaas.dispatch.pack", flush=flush):
+                if self.store is not None:
+                    payloads = [self._resolve_payload(r, t) for r in reqs]
+                else:
+                    payloads = [r.frames for r in reqs]
+                batch, slices, pad = pack_frames_device(
+                    payloads, buckets=self.batcher.pad_buckets)
             if self.store is not None:
                 for r in reqs:
-                    if isinstance(r.frames, ClaimCheck):
-                        self.store.release(r.frames, now=t)
-            raise RuntimeError(
-                f"no healthy replicas for tenant pipeline {pipe.name!r}")
-        if self.store is not None:
-            payloads = [self._resolve_payload(r, t) for r in reqs]
-        else:
-            payloads = [r.frames for r in reqs]
-        batch, slices, pad = pack_frames_device(
-            payloads, buckets=self.batcher.pad_buckets)
-        if self.store is not None:
-            for r in reqs:
-                self.store.release(r.frames, now=t)
-            self.store.sweep(t)
-        n_frames = batch.shape[0]
-        f_real = n_frames - pad
-        svc = n_frames / pipe.cloud_fps
-        queue_depth = self.batcher.pending_frames
-        if self.cost_model is not None:
-            self.cost_model.observe_pool(t, self.router.healthy_count())
-        deadline = min((r.deadline for r in reqs if r.deadline is not None),
-                       default=None)
-        timeout = max(0.0, deadline - t) if deadline is not None else None
-        out, done, _ = self.router.route(
-            pipe.cloud_stage, batch, now=t, model_time=svc,
-            queue_depth=queue_depth, replica=idx, timeout=timeout)
-        start = done - svc
-        self._detect_windows.append((start, svc))
-        self.tenant_stats["flushes"] += 1
-        self.tenant_stats["chunks"] += len(reqs)
-        self.tenant_stats["frames"] += f_real
-
-        for req, sl in zip(reqs, slices):
-            stream = req.stream
-            chunk = req.meta["chunk"]
-            f = req.frames.shape[0]
-            out_sl = out[sl]
-            coord_bytes = float(getattr(out_sl, "nbytes", 8 * f))
-            wan_down = self.network.wan_time(coord_bytes, t=done)
-            fog_time = f / pipe.fog_fps
-            result, done_c = stream.fog_exec.run(
-                pipe.fog_stage, chunk.frames, out_sl,
-                now=done + wan_down, model_time=fog_time)
-            fog_wait = (max(0.0, done_c - fog_time - (done + wan_down))
-                        if self.fog_queueing else 0.0)
-            lat = LatencyBreakdown(
-                quality_control=req.meta["qc"],
-                transmission=req.meta["wan_up"] + wan_down,
-                cloud_inference=svc,
-                fog_inference=fog_time,
-                queue_wait=max(0.0, start - req.arrival) + fog_wait)
-            billed = pipe.billed(result, f)
+                    self.store.release(r.frames, now=t)
+                self.store.sweep(t)
+            n_frames = batch.shape[0]
+            f_real = n_frames - pad
+            svc = n_frames / pipe.cloud_fps
+            queue_depth = self.batcher.pending_frames
             if self.cost_model is not None:
-                tname = self._tenant_name(stream)
-                self.cost_model.charge_cloud(
-                    tname, frames=f, invocations=billed,
-                    busy_s=svc * f / max(f_real, 1), t=t)
-                self.cost_model.charge_fog(tname, fog_time, t)
-            res = TenantChunkResult(
-                result, wan_bytes=req.meta["wan_bytes"],
-                coord_bytes=coord_bytes + pipe.out_bytes(result, f),
-                cloud_frames=billed, latency=lat)
-            self._push(req.meta["t0"] + lat.total, "finalize",
-                       dict(stream=stream, chunk=chunk, res=res,
-                            mode="cloud", learn=req.meta["learn"],
-                            t0=req.meta["t0"]))
-        self.sched_stats["model_wall_s"] += time.perf_counter() - m0
+                self.cost_model.observe_pool(t, self.router.healthy_count())
+            deadline = min((r.deadline for r in reqs
+                            if r.deadline is not None), default=None)
+            timeout = (max(0.0, deadline - t) if deadline is not None
+                       else None)
+            out, done, _ = self.router.route(
+                pipe.cloud_stage, batch, now=t, model_time=svc,
+                queue_depth=queue_depth, replica=idx, timeout=timeout)
+            start = done - svc
+            self._detect_windows.append((start, svc))
+            self.tenant_stats["flushes"] += 1
+            self.tenant_stats["chunks"] += len(reqs)
+            self.tenant_stats["frames"] += f_real
+
+            for req, sl in zip(reqs, slices):
+                stream = req.stream
+                chunk = req.meta["chunk"]
+                f = req.frames.shape[0]
+                out_sl = out[sl]
+                coord_bytes = float(getattr(out_sl, "nbytes", 8 * f))
+                wan_down = self.network.wan_time(coord_bytes, t=done)
+                fog_time = f / pipe.fog_fps
+                result, done_c = stream.fog_exec.run(
+                    pipe.fog_stage, chunk.frames, out_sl,
+                    now=done + wan_down, model_time=fog_time)
+                fog_wait = (max(0.0, done_c - fog_time - (done + wan_down))
+                            if self.fog_queueing else 0.0)
+                lat = LatencyBreakdown(
+                    quality_control=req.meta["qc"],
+                    transmission=req.meta["wan_up"] + wan_down,
+                    cloud_inference=svc,
+                    fog_inference=fog_time,
+                    queue_wait=max(0.0, start - req.arrival) + fog_wait)
+                billed = pipe.billed(result, f)
+                if self.cost_model is not None:
+                    tname = self._tenant_name(stream)
+                    self.cost_model.charge_cloud(
+                        tname, frames=f, invocations=billed,
+                        busy_s=svc * f / max(f_real, 1), t=t)
+                    self.cost_model.charge_fog(tname, fog_time, t)
+                res = TenantChunkResult(
+                    result, wan_bytes=req.meta["wan_bytes"],
+                    coord_bytes=coord_bytes + pipe.out_bytes(result, f),
+                    cloud_frames=billed, latency=lat)
+                self._push(req.meta["t0"] + lat.total, "finalize",
+                           dict(stream=stream, chunk=chunk, res=res,
+                                mode="cloud", learn=req.meta["learn"],
+                                t0=req.meta["t0"]))
 
     def _finalize(self, t: float, data: dict) -> None:
         stream, chunk = data["stream"], data["chunk"]
-        res = data["res"]
-        self.sched_stats["finalizes"] += 1
-        if data.get("inflight"):
-            # retire the in-flight future: its arrays stay device-side in
-            # the flush bundle until a consumer touches a field, so the
-            # device ran ahead on later flushes while this result waited
-            # for its event.  Identity scan, not deque.remove: == on lazy
-            # results would trigger attribute materialization.
-            for i, p in enumerate(self._inflight):
-                if p is res:
-                    del self._inflight[i]
-                    break
-        t0 = data["t0"]
-        self.monitor.record("latency", res.latency.total, t0)
-        self.monitor.record("wan_bytes", res.wan_bytes, t0)
-        self.monitor.incr("cloud_frames", res.cloud_frames)
-        tenant_tagged = stream.tenant is not None or self.cost_model is not None
-        if tenant_tagged:
-            # per-tenant attribution: tagged latency/attainment series feed
-            # throughput_report()["tenants"] and the noisy-neighbor gate
-            tname = self._tenant_name(stream)
-            self.monitor.record(f"latency:{tname}", res.latency.total, t0)
-        if self.cost_model is not None:
-            tname = self._tenant_name(stream)
-            self.cost_model.charge_egress(
-                tname, res.wan_bytes + res.coord_bytes, t0)
-            self.cost_model.note_chunk(tname)
-        if stream.slo is not None:
-            met = res.latency.total <= stream.slo + 1e-9
-            self.monitor.record("slo_attained", 1.0 if met else 0.0, t0)
+        with self._span("vpaas.finalize", stream=stream.name):
+            res = data["res"]
+            self.sched_stats["finalizes"] += 1
+            if data.get("inflight"):
+                # retire the in-flight future: its arrays stay device-side
+                # in the flush bundle until a consumer touches a field, so
+                # the device ran ahead on later flushes while this result
+                # waited for its event.  Identity scan, not deque.remove:
+                # == on lazy results would trigger attribute
+                # materialization.
+                for i, p in enumerate(self._inflight):
+                    if p is res:
+                        del self._inflight[i]
+                        break
+            t0 = data["t0"]
+            self.monitor.record("latency", res.latency.total, t0)
+            self.monitor.record("wan_bytes", res.wan_bytes, t0)
+            self.monitor.incr("cloud_frames", res.cloud_frames)
+            tenant_tagged = (stream.tenant is not None
+                             or self.cost_model is not None)
             if tenant_tagged:
-                self.monitor.record(f"slo_attained:{self._tenant_name(stream)}",
-                                    1.0 if met else 0.0, t0)
-            self.monitor.record("slo_margin",
-                                stream.slo - res.latency.total, t0)
-            if self.adaptive_margin:
-                a = self.margin_alpha
-                stream.att_ewma = ((1.0 - a) * stream.att_ewma
-                                   + a * (1.0 if met else 0.0))
-                lo, hi = self.margin_bounds
-                stream.slo_margin = lo + (hi - lo) * (1.0 - stream.att_ewma)
-        if (self.plane is None and data["learn"]
-                and stream.learner is not None
-                and data["mode"] == "cloud"
-                and not stream.learner.budget_exhausted):
-            # HITL feedback runs on the fog node's BACKGROUND lane: the
-            # stream's next chunk is never head-of-line blocked behind
-            # collect work (the PR-2 follow-up), and a nonzero hitl_cost_s
-            # prices the labeling/update time into the tenant's fog spend
-            # without touching any serving-path completion time
-            updated, done_c = stream.fog_exec.run(
-                STAGE_COLLECT, stream, chunk, res, now=t,
-                model_time=self.hitl_cost_s, priority="background")
-            if self.cost_model is not None and self.hitl_cost_s > 0:
-                self.cost_model.charge_fog(self._tenant_name(stream),
-                                           self.hitl_cost_s, done_c)
-            if updated:
-                self.monitor.incr("model_updates")
-        stream.clock = t
-        stream.results.append((chunk, res, data["mode"]))
-        stream.busy = False
-        if self.plane is not None and data["learn"]:
-            # the continual-learning plane runs beside serving: labeling and
-            # training cost background time, never this chunk's latency
-            self.plane.on_chunk(self, stream, chunk, res, t, data["mode"])
-        if data.get("inflight"):
-            # last: every consumer that runs *at* finalize (HITL collect,
-            # the learning plane) has touched its fields by now
-            res._bundle.pending -= 1
-            self._maybe_seal()
-        self._pull_next(stream)
+                # per-tenant attribution: tagged latency/attainment series
+                # feed throughput_report()["tenants"] and the noisy-neighbor
+                # gate
+                tname = self._tenant_name(stream)
+                self.monitor.record(f"latency:{tname}", res.latency.total, t0)
+            if self.cost_model is not None:
+                tname = self._tenant_name(stream)
+                self.cost_model.charge_egress(
+                    tname, res.wan_bytes + res.coord_bytes, t0)
+                self.cost_model.note_chunk(tname)
+            if stream.slo is not None:
+                met = res.latency.total <= stream.slo + 1e-9
+                self.monitor.record("slo_attained", 1.0 if met else 0.0, t0)
+                if tenant_tagged:
+                    self.monitor.record(
+                        f"slo_attained:{self._tenant_name(stream)}",
+                        1.0 if met else 0.0, t0)
+                self.monitor.record("slo_margin",
+                                    stream.slo - res.latency.total, t0)
+                if self.adaptive_margin:
+                    a = self.margin_alpha
+                    stream.att_ewma = ((1.0 - a) * stream.att_ewma
+                                       + a * (1.0 if met else 0.0))
+                    lo, hi = self.margin_bounds
+                    stream.slo_margin = (lo + (hi - lo)
+                                         * (1.0 - stream.att_ewma))
+            if (self.plane is None and data["learn"]
+                    and stream.learner is not None
+                    and data["mode"] == "cloud"
+                    and not stream.learner.budget_exhausted):
+                # HITL feedback runs on the fog node's BACKGROUND lane: the
+                # stream's next chunk is never head-of-line blocked behind
+                # collect work (the PR-2 follow-up), and a nonzero
+                # hitl_cost_s prices the labeling/update time into the
+                # tenant's fog spend without touching any serving-path
+                # completion time
+                updated, done_c = stream.fog_exec.run(
+                    STAGE_COLLECT, stream, chunk, res, now=t,
+                    model_time=self.hitl_cost_s, priority="background")
+                if self.cost_model is not None and self.hitl_cost_s > 0:
+                    self.cost_model.charge_fog(self._tenant_name(stream),
+                                               self.hitl_cost_s, done_c)
+                if updated:
+                    self.monitor.incr("model_updates")
+            stream.clock = t
+            stream.results.append((chunk, res, data["mode"]))
+            stream.busy = False
+            if self.plane is not None and data["learn"]:
+                # the continual-learning plane runs beside serving: labeling
+                # and training cost background time, never this chunk's
+                # latency
+                self.plane.on_chunk(self, stream, chunk, res, t, data["mode"])
+            if data.get("inflight"):
+                # last: every consumer that runs *at* finalize (HITL collect,
+                # the learning plane) has touched its fields by now
+                res._bundle.pending -= 1
+                self._maybe_seal()
+            self._pull_next(stream)
 
     def _maybe_seal(self) -> None:
         """Seal oldest fully-finalized bundles past the retention cap."""
