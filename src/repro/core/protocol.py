@@ -234,14 +234,12 @@ def _crop_bucket(clf_cfg: ClassifierConfig, pcfg: ProtocolConfig,
                  idxs: jax.Array) -> jax.Array:
     """The compacted classify stages' crop step: (B, h, w, 3).
 
-    ``pcfg.impl`` is a static argname of the enclosing jits, so this is a
-    trace-time branch.  ``impl="ref"`` keeps the original shared-grid
-    materialize-then-gather (the oracle structure); any kernel impl crops
-    only the B bucket rows via the ``crop_gather`` Pallas kernel.  Both
-    produce bit-identical crops (see ``ref.bilinear_crops``)."""
-    if pcfg.impl in ("ref", "ref_unchunked"):
-        crops = reg.crop_batch(frames_hq, split.prop_boxes, clf_cfg.crop_hw)
-        return crops[idxs[0], idxs[1]]
+    Crops only the B bucket rows that the gather plan ``idxs`` names, on
+    every ``impl``: ``ref`` through the jitted ``ref.crop_gather`` program,
+    kernel impls through the ``crop_gather`` Pallas kernel.  Both run the
+    bilinear program of ``ref.bilinear_crops``, so the rows are bitwise
+    what cropping the whole F x N grid and indexing it gives, pad rows
+    included, and the cost scales with B, not with F x N."""
     return ops.crop_gather(frames_hq, split.prop_boxes, idxs,
                            out_hw=clf_cfg.crop_hw, impl=pcfg.impl)
 
@@ -257,15 +255,11 @@ def classify_compacted(clf_cfg: ClassifierConfig, pcfg: ProtocolConfig,
     ``(fidx, ridx)`` index the valid proposals of the whole flush (padded to
     a bucket with out-of-bounds rows: gathers clip, scatters drop), and
     ``widx`` picks each crop's per-stream readout from the stacked ``Ws``
-    (G, d+1, C).  Only the gathered bucket rows pay the classifier-backbone
-    FLOPs — the full-budget path pays F x N — and the scores/features are
-    scattered back into zero-initialised grids, matching the masked
-    reference output bit-for-bit: the backbone is per-row deterministic,
-    and the crop stage shares one fixed-lowering bilinear program
-    (``ref.bilinear_crops``) across the shared-grid path and the
-    ``crop_gather`` kernel, so the kernel path (``impl != "ref"``) crops
-    ONLY the B bucket rows — cost scales with valid proposals, not F x N —
-    while staying bit-identical to gathering from the full grid."""
+    (G, d+1, C).  Only the B bucket rows are cropped (``_crop_bucket``,
+    bitwise the full grid's rows) and pay the classifier-backbone FLOPs —
+    the full-budget path pays F x N for both, so cost here scales with
+    valid proposals — and the scores/features are scattered back into
+    zero-initialised grids, matching the masked full-budget output."""
     fidx, ridx, widx = idxs[0], idxs[1], idxs[2]
     gathered = _crop_bucket(clf_cfg, pcfg, frames_hq, split, idxs)
     out = clf_mod.classify_multi(clf_cfg, clf_params, gathered, Ws, widx)

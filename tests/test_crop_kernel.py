@@ -1,8 +1,10 @@
 """Pallas crop_gather kernel: interpret-mode bitwise equality vs the jnp
-oracle and vs the shared-grid materialize-then-gather path, plus the
-compacted classify stages under ``impl="interpret"`` — plain, ensemble,
-and empty-flush cases."""
+oracle and vs the shared-grid materialize-then-gather path, the
+compacted stages' ``impl="ref"`` crop step (bucket rows only, bitwise the
+grid's), plus the compacted classify stages under ``impl="interpret"`` —
+plain, ensemble, and empty-flush cases."""
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -113,6 +115,82 @@ def test_bucket_boundary_sizes():
         kernel = np.asarray(ops.crop_gather(frames, boxes, idxs,
                                             out_hw=(8, 8), impl="interpret"))
         np.testing.assert_array_equal(kernel, grid)
+
+
+# ---------------------------------------------------------------------------
+# the compacted stages' crop step on impl="ref": bucket rows only
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("f,n,hw,n_valid,buckets", [
+    (4, 32, (32, 32), 11, (4, 8, 16, 32)),  # sparse: 11 rows + 5 OOB pads
+    (3, 4, (16, 16), 9, (4, 8, 16)),        # bucket 16 > F x N = 12
+])
+def test_crop_bucket_ref_matches_grid(f, n, hw, n_valid, buckets):
+    """``_crop_bucket`` on impl="ref" crops only the plan's rows, bitwise
+    what cropping the whole grid and indexing it gives, pad rows (frame
+    index F, clipped to the last frame) included."""
+    frames, boxes, _ = _rand_case(jax.random.fold_in(KEY, f * n), f, n, hw,
+                                  0.0)
+    pv = np.zeros((f, n), bool)
+    pv.ravel()[np.random.default_rng(f * n).choice(f * n, n_valid,
+                                                   replace=False)] = True
+    idxs, got_valid, bucket = _idxs(pv, buckets=buckets)
+    assert got_valid == n_valid and bucket > n_valid
+    assert (np.asarray(idxs[0, n_valid:]) == f).all()
+    split = reg.RegionSplit(boxes, jnp.zeros((f, n), jnp.int32),
+                            jnp.zeros((f, n), bool), boxes, jnp.asarray(pv))
+    crop = jax.jit(pm._crop_bucket, static_argnums=(0, 1))
+    got = np.asarray(crop(CLF, pm.ProtocolConfig(impl="ref"), frames, split,
+                          idxs))
+    want = np.asarray(_grid_gather(frames, boxes, idxs, out_hw=CLF.crop_hw))
+    assert got.shape == (bucket, *CLF.crop_hw, 3)
+    np.testing.assert_array_equal(got, want)
+
+
+def _array_sizes(text):
+    """Element counts of every array type in StableHLO (``tensor<8x3xf32>``)
+    or compiled HLO (``f32[8,3]{1,0}``) text."""
+    dims = re.findall(r"tensor<((?:\d+x)+)[a-z]", text)
+    dims = [d.rstrip("x").split("x") for d in dims]
+    dims += [d.split(",") for d in
+             re.findall(r"\b(?:f|bf|s|u|pred)\d*\[(\d+(?:,\d+)*)\]", text)]
+    return [int(np.prod([int(x) for x in d])) for d in dims]
+
+
+@pytest.mark.parametrize("stage", ["plain", "ensemble"])
+@pytest.mark.parametrize("bucket", [128, 256])
+def test_classify_compacted_ref_crops_no_region_grid(stage, bucket):
+    """The served cell's shapes (8 frames x 256 regions of 128x128, 40x40
+    crops, the full-width classifier): with impl="ref" neither the lowered
+    program nor the compiled one holds an array as large as the whole
+    region grid's crops (F x N x oh x ow x 3), so the crop step cannot
+    have gone back to cropping every region before taking the bucket's."""
+    from repro.configs.vpaas_video import CLASSIFIER
+    f, n, c = 8, 256, 3
+    oh, ow = CLASSIFIER.crop_hw
+    sds = jax.ShapeDtypeStruct
+    params = jax.eval_shape(lambda: clf_mod.init_classifier(
+        CLASSIFIER, jax.random.PRNGKey(0)))
+    boxes = sds((f, n, 4), jnp.float32)
+    split = reg.RegionSplit(boxes, sds((f, n), jnp.int32),
+                            sds((f, n), jnp.bool_), boxes,
+                            sds((f, n), jnp.bool_))
+    frames = sds((f, 128, 128, c), jnp.float32)
+    idxs = sds((3, bucket), jnp.int32)
+    w = (CLASSIFIER.feature_dim + 1, CLASSIFIER.num_classes)
+    pcfg = pm.ProtocolConfig(impl="ref")
+    if stage == "plain":
+        lowered = pm.classify_compacted.lower(
+            CLASSIFIER, pcfg, params, sds((4, *w), jnp.float32), frames,
+            split, idxs)
+    else:
+        lowered = pm.classify_compacted_ensemble.lower(
+            CLASSIFIER, pcfg, params, sds((4, 2, *w), jnp.float32),
+            sds((4, 2), jnp.float32), frames, split, idxs)
+    grid = f * n * oh * ow * c
+    for text in (lowered.as_text(), lowered.compile().as_text()):
+        sizes = _array_sizes(text)
+        assert bucket * oh * ow * c in sizes   # the bucket's crops are there
+        assert max(sizes) < grid
 
 
 # ---------------------------------------------------------------------------
