@@ -5,9 +5,9 @@ here so that later changes to the program cannot move it.
 
 The served path runs float32 arrays whose convolutions and matrix products
 take one bfloat16 pass on the TPU (the backend's DEFAULT precision); the
-reference runs them in float32 at HIGHEST.  Through the detector's four
-convolutions, or the classifier's three and its readout, that rounding
-compounds to a few 2**-8 on the logits.  Scores and box coordinates are
+reference runs them in float32 at HIGHEST.  Through the detector's
+convolutions, or the classifier's and its readout, that rounding compounds
+to a few 2**-8 on the logits.  Scores and box coordinates are
 sigmoid or softmax outputs in [0, 1] with slope at most 1/4 (softmax 1/2);
 fog features are unbounded ReLU outputs, compared relative to the chunk's
 largest.
@@ -19,7 +19,8 @@ the scenes' texture has a wavelength of 3-4 pixels and a box that moves by
 a rounding's worth of a pixel crops a different pattern; the merge runs on
 the served split and fog scores.
 
-Six numbers are compared, each against its own limit (``LIMITS``):
+Six numbers are compared, each against its own limit, which the
+configuration states under ``limits`` with its reason:
 
   boxes          largest |box coordinate| gap over every region of a frame
   split_errors   accept and proposal decisions of the section IV.B split
@@ -59,17 +60,12 @@ import numpy as np
 
 from bench import reference as ref
 
-# Limits, each between the largest reading of sound runs (lower) and the
-# smallest reading of the float8 control (upper); PERF.md gives the readings.
-# The three counts of errors are exact comparisons.
-LIMITS: Dict[str, float] = {
-    "boxes": 0.04,
-    "split_errors": 0,
-    "overlap_errors": 0,
-    "fog_scores": 0.03,
-    "fog_features": 0.025,
-    "merge_errors": 0,
-}
+
+def limits(cfg: dict) -> Dict[str, float]:
+    """The configuration's limit of each compared number: each between the
+    largest reading of sound runs (lower) and the smallest reading of its
+    control (upper), an exact comparison where it is 0."""
+    return {k: v["limit"] for k, v in cfg["limits"].items()}
 
 
 @dataclass
@@ -90,7 +86,7 @@ class Check:
     def deviation(self, key: str, value: float) -> None:
         self.dev[key] = max(self.dev[key], float(value))
 
-    def finish(self, limits: Dict[str, float] = LIMITS) -> bool:
+    def finish(self, limits: Dict[str, float]) -> bool:
         """Hold every number to its limit; True when all pass."""
         if not self.chunks or not self.proposals or not self.split_held_true:
             self.failures.append(f"nothing to compare: {self.chunks} chunks, "
@@ -103,7 +99,7 @@ class Check:
                                      f"{limits[key]:.6g}")
         return not self.failures
 
-    def numbers(self, limits: Dict[str, float] = LIMITS) -> Dict[str, list]:
+    def numbers(self, limits: Dict[str, float]) -> Dict[str, list]:
         """Each compared number beside its limit."""
         return {k: [v, limits[k]] for k, v in self.dev.items()}
 

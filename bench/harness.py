@@ -2,15 +2,19 @@
 the trace, and the check against the reference.
 
 Everything a cell is made of comes from files named in ``BENCHMARK.json``:
-its configuration (``bench/configs/<config>.json``), its traffic mix
-(``bench/traffic/<traffic>.json``, read by the one generator here) and its
-per-layer metrics (``bench/metrics/<metric>.py``, each a ``read(ctx)``).
+its configuration (``bench/configs/<config>.json``, which names its model
+family, ``bench/models/<models>.py``: weights, calibration, forwards and
+costs), its traffic mix (``bench/traffic/<traffic>.json``, read by the one
+generator here) and its per-layer metrics (``bench/metrics/<metric>.py``,
+each a ``read(ctx)``).
 
 Cameras hand the scheduler a fresh chunk object for every submission (the
-content cycles through a pool made from the seed).  The scheduler's own
-clock is simulated, so every time here is the host's wall clock, taken
-from the camera's side: a chunk's time ends when the fields its operator
-receives -- boxes, labels, valid, source -- are on the host.  The harness
+content cycles through a pool of chunks that is the same for every seed:
+the seed deals it out to the cameras, so that every seed serves the same
+work in another order).  The scheduler's own clock is simulated, so every
+time here is the host's wall clock, taken from the camera's side: a
+chunk's time ends when the fields its operator receives -- boxes, labels,
+valid, source -- are on the host.  The harness
 hooks the scheduler's finalize event (the ``plane.on_chunk`` hook that the
 learning plane uses) to touch them there.  The modelled WAN time is never
 slept: there is no WAN in a run.
@@ -106,7 +110,8 @@ class CompileCount:
 
 
 def _variant(chunk, d: int, cls):
-    """Chunk under flip x (bit 0), flip y (bit 1), transpose (bit 2)."""
+    """Chunk under flip x (bit 0), flip y (bit 1), transpose (bit 2; a
+    square frame only)."""
     f, b = chunk.frames, chunk.gt_boxes.copy()
     if d & 1:
         f = f[:, :, ::-1]
@@ -120,8 +125,14 @@ def _variant(chunk, d: int, cls):
     return cls(np.ascontiguousarray(f), b, chunk.gt_labels, chunk.content)
 
 
+def _tuple(v):
+    return tuple(_tuple(x) for x in v) if isinstance(v, list) else v
+
+
 def _tuples(d: dict) -> dict:
-    return {k: tuple(v) if isinstance(v, list) else v for k, v in d.items()}
+    """``d`` with every list, nested ones too, a tuple: hashable fields of
+    a frozen config."""
+    return {k: _tuple(v) for k, v in d.items()}
 
 
 def build_system(config: dict, det_params, clf_params):
@@ -166,31 +177,47 @@ class Cameras:
     cycled per camera from a seeded offset, a fresh chunk object per
     submission.
 
-    Pool entry j of group g is one of ``scenes`` base scenes made from the
-    seed under one of the eight flips and transposes of the square frame
-    (entry index g * pool + j picks both), so every group has content of
-    its own at the cost of making ``scenes`` scenes."""
+    The pool entries are the same for every seed: ``scenes`` base scenes
+    made from the traffic's ``scenes_seed``, each under its variants (the
+    eight flips and transposes of a square frame, the four flips of any
+    other, so that every frame keeps the shape ``hw``); entry k is scene
+    ``k // variants`` under variant ``k % variants``.  The run's seed only
+    deals the entries out to the groups and sets each camera's offset into
+    its pool, so every seed serves the same chunks in another order."""
 
     def __init__(self, traffic: dict, seed: int):
         from bench.scenes import Chunk, make_chunk
         self._chunk = Chunk
-        rng = np.random.default_rng(seed)
+        rng = np.random.default_rng(traffic["scenes_seed"])
         n, group = traffic["cameras"], traffic["content_group"]
         per_group, n_scenes = traffic["pool_chunks_per_group"], traffic["scenes"]
         groups = -(-n // group)
-        if groups * per_group > 8 * n_scenes:
-            raise ValueError("more pool entries than scenes x 8 variants")
+        hw = tuple(traffic["hw"])
+        variants = 8 if hw[0] == hw[1] else 4
+        if groups * per_group > variants * n_scenes:
+            raise ValueError(f"more pool entries than scenes x {variants} "
+                             f"variants")
         base = [make_chunk(rng, traffic["content"], num_frames=traffic["frames"],
-                           hw=tuple(traffic["hw"])) for _ in range(n_scenes)]
-        self.pools = []
-        for g in range(groups):
-            self.pools.append([_variant(base[(k // 8) % n_scenes], k % 8, Chunk)
-                               for k in range(g * per_group,
-                                              (g + 1) * per_group)])
+                           hw=hw) for _ in range(n_scenes)]
+        self.entries = [_variant(base[(k // variants) % n_scenes],
+                                 k % variants, Chunk)
+                        for k in range(groups * per_group)]
+        self.per_group = per_group
+        deal = np.random.default_rng(seed)
+        order = deal.permutation(len(self.entries))
+        self.pools = [[self.entries[i] for i in order[g * per_group:
+                                                      (g + 1) * per_group]]
+                      for g in range(groups)]
         self.pool_of = [self.pools[i // group] for i in range(n)]
-        self.pos = [int(rng.integers(per_group)) for _ in range(n)]
+        self.pos = [int(deal.integers(per_group)) for _ in range(n)]
         self.n = n
         self.made = 0
+
+    def calibration_frames(self, count: int) -> List[np.ndarray]:
+        """HQ frames of the first entry of each of the first ``count``
+        groups as they stand before the seed deals them out: the same
+        chunks for every seed."""
+        return [self.entries[g * self.per_group].frames for g in range(count)]
 
     def next(self, cam: int):
         pool = self.pool_of[cam]
@@ -209,15 +236,16 @@ class Run:
 
     def __init__(self, cell: dict, seed: int, traced: bool = False):
         import jax
-        from bench.reference import calibrate, make_weights
+        from bench.models import family
         self.cell, self.seed = cell, seed
         cfg, tr = cell["config"], cell["traffic"]
         self.traffic, self.config = tr, cfg
         self.cams = Cameras(tr, seed)
-        det, self.clf_params = make_weights(cfg["detector"],
-                                            cfg["classifier"], seed)
-        self.det_params = calibrate(cfg, det, [
-            p[0].frames for p in self.cams.pools[:cfg["weights"]["chunks"]]])
+        # the deployment's one model: the same weights for every seed
+        fam = family(cfg)
+        det, self.clf_params = fam.make_weights(cfg, cfg["weights"]["seed"])
+        self.det_params = fam.calibrate(
+            cfg, det, self.cams.calibration_frames(cfg["weights"]["chunks"]))
         jax.block_until_ready((self.det_params, self.clf_params))
         self.sched, W = build_system(cfg, self.det_params, self.clf_params)
         self.sched.plane = self            # the finalize hook
@@ -363,21 +391,31 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_start: float,
             r.start_live(win0)
         compiles0 = clock.count
         tc = None
+        win_end = win0 + seconds
         if trace:
             tdir = trace_dir_for(cell, seed)
             shutil.rmtree(tdir, ignore_errors=True)
             t_span = min(tr["trace_s"], seconds)
+            # host spans are TraceMe events, which the host tracer keeps;
+            # the Python tracer's events would only slow the host
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
             sync().block_until_ready()
             tc0, tt0 = counters(r.sched), time.perf_counter()
-            jax.profiler.start_trace(tdir)
+            jax.profiler.start_trace(tdir, profiler_options=opts)
             r.drive(tt0 + t_span)
             sync().block_until_ready()
             # the traced window ends here: stop_trace spends seconds
             # writing the trace, with no device work to record
             tc = (delta(tc0, counters(r.sched)),
                   time.perf_counter() - tt0)
+            t_stop = time.perf_counter()
             jax.profiler.stop_trace()
-        win_end = win0 + seconds
+            # the untraced rest: as much serving again as the window holds
+            # beyond the traced part, whatever stop_trace took
+            rest0 = time.perf_counter()
+            stop_s = rest0 - t_stop
+            win_end = rest0 + seconds - t_span
         r.drive(win_end)
         win1 = time.perf_counter()
         c1 = counters(r.sched)
@@ -399,9 +437,11 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_start: float,
     win_done = [(t, c, res) for t, c, res in done if t <= win1]
     window_s = win1 - win0
     frames_done = sum(c.frames.shape[0] for _, c, _ in win_done)
-    # coordinates travel back at 9 bytes per uncertain region
-    valid_crops = sum(int(round(res.coord_bytes / 9.0))
-                      for _, _, res in win_done)
+    if trace:
+        rest_s = win1 - rest0
+        # coordinates travel back at 9 bytes per uncertain region
+        rest_valid_crops = sum(int(round(res.coord_bytes / 9.0))
+                               for t, _, res in win_done if t >= rest0)
     e2e: Dict[str, float] = {"setup_s": setup_s}
     attempted = failed = 0
     if r.backlog:
@@ -429,6 +469,15 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_start: float,
         f"{in_window} programs compiled or loaded in the window "
         f"({warm_compiles} in set-up, {clock.seconds:.1f} s in all), "
         f"peak_bytes_in_use {mem}")
+    # where the window's time went: chunks finished in each quarter, and
+    # the longest waits between two finishes (a host stall shows as one)
+    ends = [win0] + sorted(t for t, _, _ in win_done) + [win1]
+    gaps = sorted(np.diff(ends))[::-1]
+    quarters = np.histogram(ends[1:-1], bins=4, range=(win0, win1))[0]
+    log(f"[window] chunks a quarter {quarters.tolist()}, longest gaps "
+        f"{[round(1e3 * float(g), 1) for g in gaps[:5]]} ms, median gap "
+        f"{1e3 * float(np.median(gaps)):.2f} ms, gaps over 50 ms "
+        f"{sum(g for g in gaps if g > 0.05):.3f} s")
 
     # the sample to check: drawn from the seed among chunks finished in the
     # window whose flush still holds its device results
@@ -451,10 +500,11 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_start: float,
     t_ref = time.perf_counter()
     W = r.clf_params["W"]
     frames = [f for f, _ in sample]
+    limits = chk.limits(r.config)
     check = chk.Check()
     chk.hold(check, r.config, r.det_params, r.clf_params, W, frames,
              [g for _, g in sample])
-    correct = check.finish()
+    correct = check.finish(limits)
     log(f"[check] {check.summary()} ({time.perf_counter() - t_ref:.1f} s)")
     out_controls = {}
     for prec in controls:
@@ -463,10 +513,10 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_start: float,
         chk.hold(cc, r.config, r.det_params, r.clf_params, W, frames,
                  ref.serve(r.config, r.det_params, r.clf_params, W, frames,
                            precision=prec))
-        cc.finish()
-        out_controls[prec] = cc.numbers()
+        cc.finish(limits)
+        out_controls[prec] = cc.numbers(limits)
         log(f"[control {prec}] {cc.summary()}; " + ", ".join(
-            f"{k} {v[0]:.6g}" for k, v in cc.numbers().items()))
+            f"{k} {v[0]:.6g}" for k, v in cc.numbers(limits).items()))
 
     kind = jax.devices()[0].device_kind
     result = {
@@ -484,10 +534,12 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_start: float,
         path = tracing.latest_xplane(tdir)
         red = tracing.reduce(path)
         shutil.rmtree(tdir, ignore_errors=True)
+        log(f"[trace] traced {tc[1]:.3f} s, stop_trace {stop_s:.3f} s, "
+            f"untraced rest {rest_s:.3f} s")
         ctx = {"config": r.config, "traffic": tr, "device_kind": kind,
-               "window": win, "window_s": window_s,
-               "valid_crops": valid_crops, "trace": red,
-               "trace_window": tc[0], "trace_s": tc[1]}
+               "window": win, "window_s": window_s, "trace": red,
+               "trace_window": tc[0], "trace_s": tc[1], "rest_s": rest_s,
+               "rest_valid_crops": rest_valid_crops}
         for m in cell["per_layer"]:
             v = metric_reader(m["name"], cell["root"])(ctx)
             if v is not None:
@@ -501,7 +553,7 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_start: float,
             f"{k} x{v['count']} {v['seconds']:.6f} s"
             for k, v in sorted(red["modules"].items(),
                                key=lambda kv: -kv[1]["seconds"])[:12]))
-    result["check"] = check.numbers()
+    result["check"] = check.numbers(limits)
     for msg in check.failures:
         log(f"[check] FAIL {msg}")
     if controls:

@@ -1,0 +1,63 @@
+"""Model families: what the benchmark knows of one kind of detector and
+classifier, one file each, named by a configuration's ``"models"`` key.
+
+A family module ``bench/models/<name>.py`` provides:
+
+  make_weights(cfg, seed) -> (det_params, clf_params)
+      the weights on the device, from ``seed`` (the configuration's
+      ``weights.seed``), in the layout the program's models read
+  calibrate(cfg, det_params, chunks) -> det_params
+      the detector set on HQ chunks (T, H, W, 3) of the cell's traffic
+  detector(det_params, images, cfg, precision) -> (boxes, loc, probs)
+      over the region grid: boxes (B, N, 4) xyxy in [0, 1], objectness
+      (B, N), class probabilities (B, N, C)
+  classifier(clf_params, crops, W, cfg, precision) -> (features, scores)
+      features (K, feature_dim + 1) with the bias-absorbing 1, one-vs-all
+      scores (K, C) under the readout ``W``
+  detector_flops_per_frame(det), classifier_flops_per_crop(clf),
+  detect_split_cost(det, frames, calls), classify_cost(clf, det, rows,
+  frames, calls)
+      operations and bytes of the served kernels, as ``bench/roofline.py``
+      describes them
+
+``precision`` is one of ``bench.reference.ROUND_TO``.  A new family is a
+new file here and a configuration that names it; nothing else changes.
+"""
+from __future__ import annotations
+
+import glob
+import importlib.util
+import os
+from types import ModuleType
+from typing import Dict, List
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+    __file__))))
+INTERFACE = ("make_weights", "calibrate", "detector", "classifier",
+             "detector_flops_per_frame", "classifier_flops_per_crop",
+             "detect_split_cost", "classify_cost")
+
+# one module per file, so that a family's jitted forwards compile once
+_loaded: Dict[str, ModuleType] = {}
+
+
+def known(root: str = ROOT) -> List[str]:
+    return sorted(os.path.basename(p)[:-3] for p in glob.glob(
+        os.path.join(root, "bench", "models", "*.py"))
+        if not os.path.basename(p).startswith("_"))
+
+
+def family(cfg: dict, root: str = ROOT) -> ModuleType:
+    """The model family that configuration ``cfg`` names (``"models"``)."""
+    name = cfg.get("models")
+    if name not in known(root):
+        raise KeyError(f"configuration {cfg.get('name')!r} names model family "
+                       f"{name!r}; known families: {known(root)}")
+    path = os.path.join(root, "bench", "models", name + ".py")
+    if path not in _loaded:
+        spec = importlib.util.spec_from_file_location(
+            "bench_models_" + name.replace(".", "_").replace("-", "_"), path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _loaded[path] = mod
+    return _loaded[path]

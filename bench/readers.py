@@ -9,17 +9,25 @@ peak).  The context holds:
                 ``detect.*``: calls, frames, padded_frames; ``hot.*``:
                 flushes, crops_classified, ...)
   window_s      the window's wall seconds
-  valid_crops   uncertain regions of the chunks finished in the run
   trace         the reduced device trace (``bench.tracing.reduce``)
   trace_window  the counters over the traced part of the window
   trace_s       the traced part's wall seconds
+  rest_s        the wall seconds of the untraced rest, served after
+                ``stop_trace`` returned (its counters are ``window`` less
+                ``trace_window``: ``bench.span_readers.rest``)
+  rest_valid_crops  uncertain regions of the chunks finished in the rest
   config, traffic, device_kind
+
+The operations and bytes of a kernel come from the configuration's model
+family (``bench.models.family``).
 """
 from __future__ import annotations
 
 from typing import Optional, Sequence
 
 from bench import roofline
+from bench.models import family
+from bench.span_readers import rest
 
 
 def sched_host_ms_per_chunk(ctx) -> Optional[float]:
@@ -73,7 +81,7 @@ def roofline_pct(ctx, modules: Sequence[str], flops: float,
 
 def detect_split_roofline(ctx, modules) -> Optional[float]:
     w, det = ctx["trace_window"], ctx["config"]["detector"]
-    flops, nbytes = roofline.detect_split_cost(
+    flops, nbytes = family(ctx["config"]).detect_split_cost(
         det, frames=w["detect.frames"] + w["detect.padded_frames"],
         calls=w["detect.calls"])
     return roofline_pct(ctx, modules, flops, nbytes)
@@ -81,7 +89,7 @@ def detect_split_roofline(ctx, modules) -> Optional[float]:
 
 def classify_roofline(ctx, modules) -> Optional[float]:
     w, cfg = ctx["trace_window"], ctx["config"]
-    flops, nbytes = roofline.classify_cost(
+    flops, nbytes = family(cfg).classify_cost(
         cfg["classifier"], cfg["detector"], rows=w["hot.crops_classified"],
         frames=w["detect.frames"], calls=w["hot.flushes"])
     return roofline_pct(ctx, modules, flops, nbytes)
@@ -89,13 +97,15 @@ def classify_roofline(ctx, modules) -> Optional[float]:
 
 def step_mfu(ctx) -> Optional[float]:
     """Useful model operations per second -- detector over every real
-    frame, classifier over every uncertain region -- over the bf16 peak."""
-    w, cfg = ctx["window"], ctx["config"]
-    flops = (roofline.detector_flops_per_frame(cfg["detector"])
-             * w["detect.frames"]
-             + roofline.classifier_flops_per_crop(cfg["classifier"])
-             * ctx["valid_crops"])
-    if flops <= 0 or ctx["window_s"] <= 0:
+    frame, classifier over every uncertain region -- over the bf16 peak,
+    in the untraced rest of the window, where the host runs as it does
+    untraced."""
+    cfg, fam = ctx["config"], family(ctx["config"])
+    flops = (fam.detector_flops_per_frame(cfg["detector"])
+             * rest(ctx)["detect.frames"]
+             + fam.classifier_flops_per_crop(cfg["classifier"])
+             * ctx["rest_valid_crops"])
+    if flops <= 0 or ctx["rest_s"] <= 0:
         return None
     peak = roofline.peaks(ctx["device_kind"])["bf16_flops"]
-    return 100.0 * flops / ctx["window_s"] / peak
+    return 100.0 * flops / ctx["rest_s"] / peak

@@ -9,12 +9,12 @@ wall time into host work and device waits, as ``sched_stats`` keys:
   prop_valid_wait_wall_s  ``vpaas.wait.prop_valid``
 
 and ``hot_path_stats["h2d_bytes"]`` counts the host bytes handed to the
-device.  The traced window runs JAX's Python tracer, which slows the host
-several times over, so the time parts are read over the untraced rest of
-the measured window (``window`` less ``trace_window``), per finished chunk
-or per flush and not per second: ``stop_trace``'s writing falls in the
-rest.  A program without the recorder has none of these keys, and every
-reader here then returns None.
+device.  The time parts are read over the untraced rest of the measured
+window (``window`` less ``trace_window``), which the harness serves for
+``seconds - trace_s`` after ``stop_trace`` returns, so that the host is
+read as it runs untraced; per finished chunk or per flush.  A program
+without the recorder has none of these keys, and every reader here then
+returns None.
 """
 from __future__ import annotations
 

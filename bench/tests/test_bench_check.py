@@ -25,6 +25,7 @@ sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
 from bench import check, harness, reference  # noqa: E402
+from bench.models import family  # noqa: E402
 from bench.scenes import make_chunk  # noqa: E402
 
 SEED = 2**31 + 5
@@ -145,8 +146,7 @@ def test_frames_left_out_of_a_flush_are_caught(monkeypatch):
 @pytest.fixture(scope="module")
 def control_readings():
     cfg = harness.load_cell("single-backlog")["config"]
-    det, clf = reference.make_weights(cfg["detector"], cfg["classifier"],
-                                      SEED)
+    det, clf = family(cfg).make_weights(cfg, SEED)
     rng = np.random.default_rng(SEED)
     chunks = [make_chunk(rng, "traffic", num_frames=8).frames
               for _ in range(2)]
@@ -161,4 +161,5 @@ def control_readings():
 
 def test_float8_control_fails(control_readings):
     c = control_readings["fp8"]
-    assert not c.finish(), c.numbers()
+    limits = check.limits(harness.load_cell("single-backlog")["config"])
+    assert not c.finish(limits), c.numbers(limits)

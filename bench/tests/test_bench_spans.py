@@ -85,3 +85,27 @@ def test_the_cell_reports_the_span_metrics():
     assert all(m in names for m in SPAN_METRICS)
     units = {m["name"]: m["unit"] for m in cell["per_layer"]}
     assert units["h2d_bytes_per_frame.backlog"] == "B"
+
+
+def test_a_traced_run_reads_the_span_metrics_and_step_mfu(monkeypatch):
+    """A traced run of the small backlog cell on the CPU: the untraced rest
+    after ``stop_trace`` holds serving, so the span parts and ``step_mfu``
+    read a number.  (The CPU is given a peak here only so that the MFU
+    arithmetic runs; no device number comes from this run.)"""
+    import time
+
+    import jax
+
+    from bench import roofline
+    sys.path.insert(0, os.path.join(ROOT, "src"))
+    monkeypatch.setitem(roofline.PEAKS, jax.devices()[0].device_kind,
+                        {"bf16_flops": 1e12, "hbm_bytes_per_s": 1e11})
+    cell = harness.load_cell("single-backlog")
+    cell["traffic"].update(cameras=4, scenes=1, check_chunks=4,
+                           warmup_quiet_s=0.5, warmup_max_s=120.0,
+                           trace_s=1.0)
+    res = harness.run(cell, 2**31 + 7, 3.0, True, time.perf_counter())
+    for m in SPAN_METRICS[:4] + ("step_mfu",):
+        assert isinstance(res["metrics"][m]["value"], float), m
+    assert res["metrics"]["step_mfu"]["value"] > 0
+    assert res["device"]["window_s"] > 0
