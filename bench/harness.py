@@ -49,6 +49,10 @@ BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 RESULT_FIELDS = ("boxes", "labels", "valid", "source")
 CHECK_FIELDS = RESULT_FIELDS + ("prop_boxes", "prop_valid", "fog_scores",
                                  "fog_features")
+# read with the check's fields where a result has it: each slot's region
+# identity, by which the check matches served regions to the reference's
+# (bench/check.py); never part of a chunk's timed download
+REGION_IDS = "region_ids"
 
 
 def log(msg: str) -> None:
@@ -488,8 +492,11 @@ def run(cell: dict, seed: int, seconds: float, trace: bool, t_start: float,
         _, c, res = win_done[i]
         try:
             got = {f: np.asarray(getattr(res, f)) for f in CHECK_FIELDS}
+            ids = getattr(res, REGION_IDS, None)
         except RuntimeError:          # flush sealed past the retention cap
             continue
+        if ids is not None:
+            got[REGION_IDS] = np.asarray(ids)
         sample.append((c.frames, got))
         if len(sample) == want_n:
             break

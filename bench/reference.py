@@ -195,8 +195,11 @@ def _pow2(n: int) -> int:
 def detect(cfg: dict, det_params, chunks: List[np.ndarray],
            precision: str = "highest") -> List[Dict[str, np.ndarray]]:
     """Cloud side of each HQ chunk (T, H, W, 3): the detector outputs
-    (``boxes``, ``loc_scores``, ``cls_probs``) on the decoded LQ frames and
-    the split (``acc_valid``, ``acc_labels``, ``prop_valid``)."""
+    (``boxes``, ``loc_scores``, ``cls_probs``) on the decoded LQ frames,
+    each slot's region identity (``ids``: the family's, or the slot index
+    where it gives none; an empty slot, -1, takes part in no split), the
+    family's ``selection`` record where it returns one, and the split
+    (``acc_valid``, ``acc_labels``, ``prop_valid``)."""
     pc = cfg["protocol"]
     fam = family(cfg)
     if not pc["inter_coding"]:
@@ -204,12 +207,18 @@ def detect(cfg: dict, det_params, chunks: List[np.ndarray],
     out = []
     for hq in chunks:
         lq = encode(jnp.asarray(hq), pc["r_low"], pc["q_low"])
-        boxes, loc, probs = (np.asarray(a) for a in fam.detector(
-            det_params, lq, cfg, precision))
+        det = fam.detector(det_params, lq, cfg, precision)
+        boxes, loc, probs = (np.asarray(a) for a in det[:3])
+        ids = (np.asarray(det[3], np.int32) if len(det) > 3 else
+               np.broadcast_to(np.arange(loc.shape[1], dtype=np.int32),
+                               loc.shape))
+        loc = np.where(ids >= 0, loc, np.zeros((), loc.dtype))
         parts = [split(boxes[f], loc[f], probs[f], pc)
                  for f in range(len(boxes))]
         res = {k: np.stack([p[k] for p in parts]) for k in parts[0]}
-        res.update(boxes=boxes, loc_scores=loc, cls_probs=probs)
+        res.update(boxes=boxes, loc_scores=loc, cls_probs=probs, ids=ids)
+        if len(det) > 4:
+            res["selection"] = {k: np.asarray(v) for k, v in det[4].items()}
         out.append(res)
     return out
 
@@ -247,7 +256,8 @@ def serve(cfg: dict, det_params, clf_params, W, chunks: List[np.ndarray],
     """The whole path for each HQ chunk: ``detect``, ``fog`` at its own
     proposals, and the merge into the ChunkResult fields (``boxes``,
     ``labels``, ``valid``, ``source``, ``prop_boxes``, ``prop_valid``,
-    ``fog_scores``, ``fog_features``)."""
+    ``fog_scores``, ``fog_features``) with the region identities
+    ``region_ids``, as a program that carries them serves them."""
     pc = cfg["protocol"]
     out = detect(cfg, det_params, chunks, precision)
     fogs = fog(cfg, clf_params, W, chunks, [r["boxes"] for r in out],
@@ -261,4 +271,5 @@ def serve(cfg: dict, det_params, clf_params, W, chunks: List[np.ndarray],
         r["valid"] = r["acc_valid"] | fog_valid
         r["source"] = np.where(r["acc_valid"], 0, 1).astype(np.int32)
         r["prop_boxes"] = r["boxes"]
+        r["region_ids"] = r["ids"]
     return out
