@@ -114,6 +114,17 @@ def split_regions_dynamic(
     return RegionSplit(boxes, labels, acc_valid, boxes, prop_valid)
 
 
+def nms_rounds(acc_valid: np.ndarray, prop_valid: np.ndarray) -> int:
+    """Rounds that a split's two greedy NMS passes ran over its frames.
+
+    ``acc_valid`` and ``prop_valid`` are the passes' (F, N) keep masks.
+    A pass keeps one box a round in each frame that still has a candidate
+    alive and stops once none has, so it runs as many rounds as its
+    largest per-frame kept count."""
+    return int(np.sum(acc_valid, axis=1).max(initial=0)
+               + np.sum(prop_valid, axis=1).max(initial=0))
+
+
 def coordinate_bytes(split: RegionSplit) -> jax.Array:
     """Bytes for the returned coordinates (paper: 'only several bytes').
 

@@ -82,8 +82,8 @@ def ssd_step(x, dt, A, B, C, state):
 
 def nms_mask(boxes, scores, valid, *, iou_threshold: float = 0.45,
              impl: str = "ref"):
-    # greedy NMS is inherently sequential over selections; the Pallas win is
-    # in the pairwise-IoU matrix, which iou_matrix() covers.
+    # greedy NMS is inherently sequential over selections, and a round
+    # needs only the kept box's IoU row: no matrix for a kernel to tile.
     del impl
     return ref.nms_mask(boxes, scores, valid, iou_threshold)
 

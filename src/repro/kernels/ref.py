@@ -292,23 +292,29 @@ def iou_matrix(boxes_a: jax.Array, boxes_b: jax.Array) -> jax.Array:
 
 def nms_mask(boxes: jax.Array, scores: jax.Array, valid: jax.Array,
              iou_threshold: float = 0.45) -> jax.Array:
-    """Greedy non-maximum suppression; fixed-shape (returns keep mask)."""
-    n = boxes.shape[0]
-    iou = iou_matrix(boxes, boxes)
-    neg = jnp.asarray(NEG_INF, scores.dtype)
+    """Greedy non-maximum suppression; fixed-shape (returns keep mask).
 
-    def body(_, st):
+    Each round keeps the alive candidate with the highest score (the lowest
+    slot among equal scores) and suppresses the alive ones whose IoU with
+    it reaches the threshold; a slot scored ``NEG_INF`` or lower is never
+    kept.  The loop ends once nothing is alive, so it runs one round per
+    kept box (under ``vmap``: as many as the frame that keeps the most),
+    and a round computes only the kept box's IoU row, never an (N, N)
+    matrix."""
+    n = boxes.shape[0]
+    neg = jnp.asarray(NEG_INF, scores.dtype)
+    slots = jnp.arange(n)
+
+    def body(st):
         keep, alive = st
-        masked = jnp.where(alive, scores, neg)
-        idx = jnp.argmax(masked)
-        has = masked[idx] > neg
-        keep = keep | (has & (jnp.arange(n) == idx))
-        suppress = (iou[idx] >= iou_threshold) | (jnp.arange(n) == idx)
-        alive = jnp.where(has, alive & ~suppress, alive)
+        idx = jnp.argmax(jnp.where(alive, scores, neg))
+        iou = iou_matrix(boxes[idx][None], boxes)[0]
+        keep = keep | (slots == idx)
+        alive = alive & ~((iou >= iou_threshold) | (slots == idx))
         return keep, alive
 
-    keep, _ = jax.lax.fori_loop(0, n, body,
-                                (jnp.zeros(n, bool), valid))
+    keep, _ = jax.lax.while_loop(lambda st: jnp.any(st[1]), body,
+                                 (jnp.zeros(n, bool), valid & (scores > neg)))
     return keep
 
 

@@ -571,9 +571,10 @@ class GraphScheduler:
         self.sched_stats = {"events": 0, "finalizes": 0,
                             "step_wall_s": 0.0, "model_wall_s": 0.0}
         # wall-clock accounting for the jit'd detect stage (throughput
-        # lever): wall_s is the vpaas.detect span
+        # lever): wall_s is the vpaas.detect span; nms_rounds the rounds
+        # the split's two greedy NMS passes ran (regions.nms_rounds)
         self.detect_stats = {"calls": 0, "frames": 0, "padded_frames": 0,
-                             "wall_s": 0.0}
+                             "nms_rounds": 0, "wall_s": 0.0}
         self._spans = SpanRecorder(self.sched_stats, {
             "vpaas.step": (self.sched_stats, "step_wall_s"),
             "vpaas.dispatch": (self.sched_stats, "model_wall_s"),
@@ -1327,7 +1328,9 @@ class GraphScheduler:
                                                               det_i)
             with self._span("vpaas.wait.prop_valid", flush=flush):
                 coord_bytes = float(coord_bytes)
-                n_crops = int(np.sum(np.asarray(split.prop_valid)))
+                pv, av = jax.device_get((split.prop_valid, split.acc_valid))
+                n_crops = int(np.sum(pv))
+            self.detect_stats["nms_rounds"] += reg.nms_rounds(av, pv)
             wan_down = self.network.wan_time(coord_bytes, t=done)
             self.hot_path_stats["host_syncs"] += 2   # the two scalar reads
             clf_time = proto.fog.classify_time(max(n_crops, 1))
@@ -1430,10 +1433,12 @@ class GraphScheduler:
                     queue_depth=queue_depth, timeout=timeout, hedge=hedge)
             # THE flush's single blocking device->host read: per-chunk coord
             # bytes, crop counts, and the compaction gather plan are all
-            # derived from this one (F, N) bool mask on the host
+            # derived from this one (F, N) bool mask on the host; the
+            # accepted mask rides along for the NMS round count
             with self._span("vpaas.wait.prop_valid", flush=flush):
-                pv = np.asarray(split.prop_valid)
+                pv, av = jax.device_get((split.prop_valid, split.acc_valid))
             self.hot_path_stats["host_syncs"] += 1
+            self.detect_stats["nms_rounds"] += reg.nms_rounds(av, pv)
             self.detect_stats["calls"] += 1
             self.detect_stats["frames"] += n_frames - pad
             self.detect_stats["padded_frames"] += pad

@@ -1,10 +1,13 @@
 """Pallas kernel validation: shape/dtype sweeps, interpret mode vs the
 pure-jnp oracles in repro.kernels.ref."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import regions as reg
 from repro.kernels import ops, ref
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.flash_attention import flash_attention
@@ -226,6 +229,170 @@ def test_nms_removes_duplicates():
     scores = jnp.asarray([0.9, 0.8, 0.7])
     keep = ref.nms_mask(boxes, scores, jnp.ones(3, bool), 0.5)
     assert keep.tolist() == [True, False, True]
+
+
+NMS_IOU = 0.45      # split_regions' threshold
+NMS_MARGIN = 1e-4   # every IoU of a case lies at least this far from it
+
+
+def _iou_row(box, boxes):
+    """float64 IoU of one box against each of ``boxes``."""
+    iw = np.maximum(np.minimum(box[2], boxes[:, 2])
+                    - np.maximum(box[0], boxes[:, 0]), 0.0)
+    ih = np.maximum(np.minimum(box[3], boxes[:, 3])
+                    - np.maximum(box[1], boxes[:, 1]), 0.0)
+    inter = iw * ih
+    area = lambda b: (b[..., 2] - b[..., 0]) * (b[..., 3] - b[..., 1])
+    return inter / (area(box) + area(boxes) - inter)
+
+
+def _greedy_nms(boxes, scores, valid):
+    """Plain greedy NMS of one frame in float64: keep the highest alive
+    score (the lowest slot among equals), drop what it overlaps."""
+    keep = np.zeros(len(scores), bool)
+    alive = valid & (scores > ref.NEG_INF)
+    while alive.any():
+        i = np.flatnonzero(alive)[np.argmax(scores[alive])]
+        keep[i] = True
+        alive &= _iou_row(boxes[i], boxes) < NMS_IOU
+        alive[i] = False
+    return keep
+
+
+def _grid_boxes(rng, n):
+    """n boxes on the 1/64 grid, a quarter of them duplicates of others,
+    drawn until no pair's IoU lies within NMS_MARGIN of NMS_IOU."""
+    boxes = np.zeros((0, 4))
+    while len(boxes) < n:
+        if len(boxes) and rng.random() < 0.25:
+            box = boxes[rng.integers(len(boxes))]
+        else:
+            xy = rng.integers(0, 49, 2)
+            box = np.concatenate([xy, xy + rng.integers(4, 17, 2)]) / 64.0
+        if len(boxes) and np.any(
+                np.abs(_iou_row(box, boxes) - NMS_IOU) < NMS_MARGIN):
+            continue
+        boxes = np.vstack([boxes, box])
+    return boxes
+
+
+def _nms_case(kind, f, n, rng):
+    """(boxes (F, N, 4), scores (F, N), valid (F, N)) of one case."""
+    if kind == "chain":
+        # slot i overlaps i + 1 at IoU 0.5 and i + 2 at 0.2, scores fall
+        # along the chain: every other box survives, N / 2 rounds
+        x = np.arange(n) / 64.0
+        box = np.stack([x, np.zeros(n), x + 3 / 64.0, np.ones(n)], -1)
+        return (np.broadcast_to(box, (f, n, 4)),
+                np.broadcast_to(1.0 - np.arange(n) / n, (f, n)),
+                np.ones((f, n), bool))
+    boxes = np.stack([_grid_boxes(rng, n) for _ in range(f)])
+    scores = rng.integers(0, 16, (f, n)) / 16.0     # many equal scores
+    valid = rng.random((f, n)) < 0.6
+    if kind == "equal_scores":
+        scores[:] = 0.5
+    elif kind == "empty_frame":
+        valid[f // 2] = False
+    elif kind == "all_empty":
+        valid[:] = False
+    elif kind == "neg_inf":
+        scores[rng.random((f, n)) < 0.3] = ref.NEG_INF
+        scores[:, 0] = 2 * ref.NEG_INF
+    return boxes, scores, valid
+
+
+@pytest.mark.parametrize("f", [1, 8, 64])
+@pytest.mark.parametrize("kind", ["random", "equal_scores", "empty_frame",
+                                  "all_empty", "neg_inf", "chain"])
+def test_nms_mask_matches_greedy_oracle(kind, f, monkeypatch):
+    """ops.nms_mask, vmapped over a flush's frames as split_regions calls
+    it, against plain greedy NMS.  Coordinates on the 1/64 grid make every
+    area and intersection exact in float32, so only the IoU's division
+    rounds (relative error 2**-24); with every IoU at least NMS_MARGIN from
+    the threshold, float32 and the oracle's float64 decide alike.  The
+    loop's rounds, counted by a callback in its body, are the largest
+    per-frame kept count, as regions.nms_rounds reads them."""
+    rng = np.random.default_rng([f, len(kind)])
+    boxes, scores, valid = _nms_case(kind, f, 256, rng)
+    want = np.stack([_greedy_nms(*a) for a in zip(boxes, scores, valid)])
+
+    rounds = []
+    while_loop = jax.lax.while_loop
+
+    def counted(cond, body, init):
+        def body_counted(st):
+            jax.debug.callback(lambda: rounds.append(1))
+            return body(st)
+        return while_loop(cond, body_counted, init)
+
+    monkeypatch.setattr(jax.lax, "while_loop", counted)
+    got = jax.jit(jax.vmap(lambda b, s, v: ops.nms_mask(
+        b, s, v, iou_threshold=NMS_IOU)))(
+            jnp.asarray(boxes, jnp.float32), jnp.asarray(scores, jnp.float32),
+            jnp.asarray(valid))
+    got = np.asarray(got)
+    np.testing.assert_array_equal(got, want)
+    assert len(rounds) == want.sum(axis=1).max()
+    assert len(rounds) == reg.nms_rounds(got, np.zeros_like(got))
+    if kind == "chain":
+        assert len(rounds) == 128
+    if kind == "all_empty" or (kind == "empty_frame" and f == 1):
+        assert len(rounds) == 0
+
+
+def _loops_text(hlo: str) -> str:
+    """The compiled HLO's while loops: their result shapes and the text of
+    every computation their bodies and conditions reach."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    calls = re.compile(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)")
+    shapes, todo = [], []
+    for line in hlo.splitlines():
+        if " while(" in line:
+            shapes.append(line.split(" while(")[0])
+            todo += calls.findall(line)
+    seen = set()
+    while todo:
+        c = todo.pop()
+        if c not in seen:
+            seen.add(c)
+            shapes += comps[c]
+            todo += calls.findall("\n".join(comps[c]))
+    return "\n".join(shapes)
+
+
+@pytest.mark.parametrize("program", ["nms_mask", "detect_split"])
+def test_nms_builds_no_pairwise_iou_matrix(program):
+    """At the served cell's shapes (8 frames of 128x128, 256 slots) the
+    NMS passes hold no (F, N, N) IoU matrix: the vmapped nms_mask has no
+    such array at all, and no while loop of detect_split carries or
+    computes one (its §IV.B filter's (F, N, M) IoU against the accepted
+    regions is computed once, outside the loops)."""
+    from repro.configs.vpaas_video import DETECTOR
+    from repro.core import protocol as pm
+    from repro.models import detector as det_mod
+    f, n = 8, 256
+    sds = jax.ShapeDtypeStruct
+    if program == "nms_mask":
+        text = jax.jit(jax.vmap(lambda b, s, v: ops.nms_mask(b, s, v))).lower(
+            sds((f, n, 4), jnp.float32), sds((f, n), jnp.float32),
+            sds((f, n), jnp.bool_)).compile().as_text()
+    else:
+        assert np.prod(DETECTOR.grid_hw) == n
+        params = jax.eval_shape(lambda: det_mod.init_detector(
+            DETECTOR, jax.random.PRNGKey(0)))
+        hlo = pm.detect_split.lower(
+            DETECTOR, pm.ProtocolConfig(), params,
+            sds((f, 128, 128, 3), jnp.float32)).compile().as_text()
+        assert hlo.count(" while(") == 2
+        text = _loops_text(hlo)
+    assert f"f32[{f},{n},{n}]" not in text
 
 
 # ---------------------------------------------------------------------------
