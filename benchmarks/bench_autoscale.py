@@ -54,7 +54,7 @@ def _run(graph, ctx: BenchContext, policy: str, waves, frames: int):
     replicas = MAX_REPLICAS if scaler is None else 1
     sched = GraphScheduler(
         graph, batcher=CrossStreamBatcher(max_chunks=6, window=0.05),
-        hot_path="fused", cost_model=cost, cloud_replicas=replicas,
+        cost_model=cost, cloud_replicas=replicas,
         autoscaler=scaler, scale_unit="replicas",
         cold_start_s=COLD_START_S)
     n_streams = max(w[0] for w in waves)

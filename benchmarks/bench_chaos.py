@@ -109,7 +109,7 @@ class _Harness:
             self.graph, num_shards=shards, store=store,
             batcher_factory=lambda i: CrossStreamBatcher(max_chunks=4,
                                                          window=0.05),
-            hot_path="fused", cloud_replicas=self.replicas, fault=fault,
+            cloud_replicas=self.replicas, fault=fault,
             hedging=hedging,
             # cross-scenario bitwise comparison reads every result after
             # the run; sealing would discard the fields first

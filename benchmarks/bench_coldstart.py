@@ -117,7 +117,7 @@ class _Harness:
         return GraphScheduler(
             self.graph,
             batcher=CrossStreamBatcher(max_chunks=4, window=0.05),
-            hot_path="fused", cost_model=cost, cloud_replicas=replicas,
+            cost_model=cost, cloud_replicas=replicas,
             autoscaler=autoscaler,
             scale_unit="replicas" if autoscaler is not None else "devices",
             cold_start_s=COLD_START_S, warm_pool=warm_pool)
@@ -185,7 +185,7 @@ class _Harness:
             self.graph, num_shards=shards, use_store=False,
             batcher_factory=lambda i: CrossStreamBatcher(max_chunks=4,
                                                          window=0.05),
-            hot_path="fused", cloud_replicas=2, warm_pool=warm_pool)
+            cloud_replicas=2, warm_pool=warm_pool)
         states = [sched.add_stream(f"cam{i:03d}", W=self.clf_params["W"],
                                    slo=SLO_S)
                   for i in range(self.n_streams)]

@@ -30,7 +30,7 @@ def _serving_bill(ctx: BenchContext, datasets) -> dict:
     cost = CostModel()
     sched = GraphScheduler(
         graph, batcher=CrossStreamBatcher(max_chunks=4, window=0.05),
-        hot_path="fused", cost_model=cost)
+        cost_model=cost)
     streams = {name: sched.add_stream(name, W=ctx.clf_params["W"])
                for name in datasets}
     for name, chunks in datasets.items():

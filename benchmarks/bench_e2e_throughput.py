@@ -1,24 +1,17 @@
-"""End-to-end wall-clock throughput: device-resident fused hot path vs the
-pre-fusion synchronous path (the PR-4 regression gate).
+"""End-to-end device-residency counters of the served hot path.
 
-Both modes run the *same* multi-stream workload through the same
-event-driven scheduler; only the hot path differs:
+One multi-stream workload runs through the event-driven scheduler's one
+hot path: device-side packing, one fused ``cloud.detect_split`` dispatch
+and ONE blocking host read per flush, one compacted bucketed cross-stream
+``fog.classify_batched`` dispatch, results drained as device futures at
+finalize.
 
-  * ``sync``  — numpy cross-stream packing, blocking detect, one
-    ``split_uncertain`` jit call + two scalar device syncs per chunk,
-    full-budget F x N classify per chunk, eager result materialization
-    (the pre-PR execution model);
-  * ``fused`` — device-side packing, one fused ``cloud.detect_split``
-    dispatch + ONE blocking host read per flush, one compacted bucketed
-    cross-stream ``fog.classify_batched`` dispatch, results drained as
-    device futures at finalize.
-
-Reported (and written to ``BENCH_e2e.json``): wall-clock end-to-end
-frames/sec per mode, speedup, host syncs per flush, detect-device
-occupancy, compacted-classify FLOPs saved, and the in-flight future depth.
-The gate is >=2x wall frames/sec at 8 streams, plus bit-identical results
-between the two modes (batching changes *when* things run, never *what*
-they compute).
+Reported (and written to ``BENCH_e2e.json``): host syncs per flush, the
+share of full-grid classify FLOPs the compaction avoided, readout uploads
+and the in-flight future depth.  These are counts, the same on any
+backend; no wall-clock figure is reported, because a CPU time says nothing
+about the chip (the chip benchmark lives under ``bench/``).  The gate is
+one host sync per flush.
 
 Usage:
   PYTHONPATH=src python benchmarks/bench_e2e_throughput.py            # gate
@@ -30,7 +23,6 @@ from __future__ import annotations
 import argparse
 import os
 import sys
-import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
@@ -46,10 +38,8 @@ from repro.models import classifier as clf_mod
 from repro.models import detector as det_mod
 from repro.video import synthetic
 
-# Small models: the hot path's levers (dispatch fusion, sync elimination,
-# crop compaction) dominate exactly when per-invocation overhead does —
-# the serverless many-cheap-calls regime.  Stage throughput is weight-
-# independent, so untrained params are fine.
+# Small models: the counters depend on the dataflow, not on model size or
+# weights, so untrained params are fine.
 BENCH_DET = DetectorConfig(name="bench-e2e-det", image_hw=(32, 32),
                            widths=(8, 16))
 BENCH_CLF = ClassifierConfig(name="bench-e2e-clf", crop_hw=(16, 16),
@@ -62,118 +52,34 @@ def _streams(n_streams: int, chunks: int, frames: int):
              for j in range(chunks)] for i in range(n_streams)]
 
 
-def _run_mode(det_params, clf_params, streams, *, hot_path: str,
-              window: float):
-    multi = MultiStreamCoordinator(HighLowProtocol(BENCH_DET, BENCH_CLF),
-                                   det_params, clf_params, streams,
-                                   max_batch_chunks=len(streams),
-                                   batch_window=window, hot_path=hot_path)
-    # time the serving drain only (submit -> every chunk finalized +
-    # materialized); the F1 evaluation below is offline bookkeeping, not
-    # part of either hot path
-    sched = multi.scheduler
-    t0 = time.perf_counter()
-    for state, spec in zip(multi._states, multi.specs):
-        for chunk in spec.chunks:
-            sched.submit(state, chunk, learn=False)
-    sched.run_until_idle()
-    wall = time.perf_counter() - t0
-    out = multi.results()
-    rep = multi.report()
-    frames = sum(c.frames.shape[0] for chunks in streams for c in chunks)
-    return {"wall_s": wall, "frames": frames, "fps": frames / wall,
-            "report": rep, "out": out, "multi": multi}
-
-
-def _assert_identical(a, b) -> None:
-    """fused and sync must disagree on nothing but wall-clock."""
-    for name in a["out"]:
-        ra, rb = a["out"][name], b["out"][name]
-        assert ra.f1 == rb.f1, name
-        assert ra.bandwidth == rb.bandwidth, name
-        assert ra.latencies == rb.latencies, name
-    for name, st_a in a["multi"].scheduler.streams.items():
-        st_b = b["multi"].scheduler.streams[name]
-        for (_, r1, _), (_, r2, _) in zip(st_a.results, st_b.results):
-            assert np.array_equal(r1.boxes, r2.boxes)
-            assert np.array_equal(r1.labels, r2.labels)
-            assert np.array_equal(r1.valid, r2.valid)
-            assert np.array_equal(r1.fog_features, r2.fog_features)
-
-
 def bench(n_streams: int = 8, chunks: int = 4, frames: int = 2,
-          window: float = 0.05, repeats: int = 5):
+          window: float = 0.05):
     det_params = det_mod.init_detector(BENCH_DET, jax.random.PRNGKey(0))
     clf_params = clf_mod.init_classifier(BENCH_CLF, jax.random.PRNGKey(1))
     streams = _streams(n_streams, chunks, frames)
-
-    # warm both hot paths' jit caches (every batch/bucket shape compiles
-    # here), check bit-identity once, then measure fresh coordinators
-    warm_sync = _run_mode(det_params, clf_params, streams,
-                          hot_path="sync", window=window)
-    warm_fused = _run_mode(det_params, clf_params, streams,
-                           hot_path="fused", window=window)
-    _assert_identical(warm_fused, warm_sync)
-
-    # back-to-back sync/fused pairs: ambient machine contention hits a
-    # pair's two halves roughly equally, so the *median paired ratio* is a
-    # far stabler speedup estimate on shared hardware than a ratio of
-    # independent bests (which one noisy minute can skew either way)
-    runs = {"sync": [], "fused": []}
-    ratios = []
-    for _ in range(max(1, repeats)):
-        rs = _run_mode(det_params, clf_params, streams,
-                       hot_path="sync", window=window)
-        rf = _run_mode(det_params, clf_params, streams,
-                       hot_path="fused", window=window)
-        runs["sync"].append(rs)
-        runs["fused"].append(rf)
-        ratios.append(rf["fps"] / rs["fps"])
-    # the gated speedup is the median paired ratio; report THAT pair's fps
-    # so the artifact is self-consistent (fused/sync == speedup exactly),
-    # with the best-of walls alongside for reference
-    mid = int(np.argsort(ratios)[len(ratios) // 2])
-    med = {m: runs[m][mid] for m in runs}
-    best = {m: min(rs_, key=lambda r: r["wall_s"])
-            for m, rs_ in runs.items()}
-    speedup = med["fused"]["fps"] / med["sync"]["fps"]
-
-    rf, rs = med["fused"]["report"], med["sync"]["report"]
+    multi = MultiStreamCoordinator(HighLowProtocol(BENCH_DET, BENCH_CLF),
+                                   det_params, clf_params, streams,
+                                   max_batch_chunks=len(streams),
+                                   batch_window=window)
+    multi.run(learn=False)
+    rep = multi.report()
     payload = {
         "workload": {"streams": n_streams, "chunks_per_stream": chunks,
                      "frames_per_chunk": frames, "window": window,
-                     "total_frames": med["fused"]["frames"]},
-        "wall_fps_fused": med["fused"]["fps"],
-        "wall_fps_sync": med["sync"]["fps"],
-        "wall_s_fused": med["fused"]["wall_s"],
-        "wall_s_sync": med["sync"]["wall_s"],
-        "wall_s_fused_best": best["fused"]["wall_s"],
-        "wall_s_sync_best": best["sync"]["wall_s"],
-        "speedup": speedup,
-        "paired_ratios": [round(r, 3) for r in ratios],
-        "host_syncs_per_flush_fused": rf.get("host_syncs_per_flush", 0.0),
-        "host_syncs_per_flush_sync": rs.get("host_syncs_per_flush", 0.0),
-        "detect_occupancy_fused": rf.get("detect_occupancy", 0.0),
-        "detect_occupancy_sync": rs.get("detect_occupancy", 0.0),
-        "classify_flops_saved_frac": rf.get("classify_flops_saved_frac",
-                                            0.0),
-        "inflight_peak": rf.get("hot_inflight_peak", 0),
-        "w_uploads_fused": rf.get("w_uploads", 0),
-        "detect_calls_fused": rf.get("calls", 0),
-        "detect_calls_sync": rs.get("calls", 0),
-        "bit_identical": True,
+                     "total_frames": n_streams * chunks * frames},
+        "host_syncs_per_flush_fused": rep.get("host_syncs_per_flush", 0.0),
+        "classify_flops_saved_frac": rep.get("classify_flops_saved_frac",
+                                             0.0),
+        "inflight_peak": rep.get("hot_inflight_peak", 0),
+        "w_uploads_fused": rep.get("w_uploads", 0),
     }
     rows = [{
         "name": f"{n_streams}streams_x{chunks}chunks_x{frames}f",
-        "us_per_call": f"{1e6 * med['fused']['wall_s']:.0f}",
-        "fused_fps": f"{med['fused']['fps']:.0f}",
-        "sync_fps": f"{med['sync']['fps']:.0f}",
-        "speedup": f"{speedup:.2f}",
-        "syncs_per_flush_fused": f"{payload['host_syncs_per_flush_fused']:.1f}",
-        "syncs_per_flush_sync": f"{payload['host_syncs_per_flush_sync']:.1f}",
+        "flushes": rep["hot_flushes"],
+        "syncs_per_flush": f"{payload['host_syncs_per_flush_fused']:.1f}",
         "flops_saved": f"{payload['classify_flops_saved_frac']:.2f}",
-        "occupancy": f"{payload['detect_occupancy_fused']:.2f}",
-        "bit_identical": "ok",
+        "inflight_peak": payload["inflight_peak"],
+        "w_uploads": payload["w_uploads_fused"],
     }]
     return rows, payload
 
@@ -181,8 +87,7 @@ def bench(n_streams: int = 8, chunks: int = 4, frames: int = 2,
 def run(ctx=None, quick: bool = False):
     """benchmarks.run entry point — also emits artifacts/BENCH_e2e.json."""
     rows, payload = bench(n_streams=4 if quick else 8,
-                          chunks=2 if quick else 4,
-                          repeats=1 if quick else 3)
+                          chunks=2 if quick else 4)
     write_json(payload, os.path.join(os.path.dirname(__file__), "..",
                                      "artifacts", "BENCH_e2e.json"))
     return rows
@@ -191,59 +96,34 @@ def run(ctx=None, quick: bool = False):
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true",
-                    help="small run, no speedup threshold (CI smoke)")
+                    help="small run (CI smoke)")
     ap.add_argument("--streams", type=int, default=8)
     ap.add_argument("--chunks", type=int, default=4)
     ap.add_argument("--frames", type=int, default=2)
     ap.add_argument("--window", type=float, default=0.05)
-    ap.add_argument("--repeats", type=int, default=5)
     ap.add_argument("--json", default="BENCH_e2e.json",
                     help="write machine-readable results here")
     args = ap.parse_args()
 
     if args.quick:
         rows, payload = bench(n_streams=4, chunks=2, frames=args.frames,
-                              window=args.window, repeats=1)
+                              window=args.window)
     else:
         rows, payload = bench(n_streams=args.streams, chunks=args.chunks,
-                              frames=args.frames, window=args.window,
-                              repeats=args.repeats)
-        if payload["speedup"] < 2.0:
-            # shared-hardware insurance: a noisy neighbour can depress one
-            # whole measurement window; re-measure once before failing
-            print(f"# median {payload['speedup']:.2f}x below gate — "
-                  "re-measuring once", file=sys.stderr)
-            rows2, payload2 = bench(n_streams=args.streams,
-                                    chunks=args.chunks, frames=args.frames,
-                                    window=args.window,
-                                    repeats=args.repeats)
-            if payload2["speedup"] > payload["speedup"]:
-                rows, payload = rows2, payload2
+                              frames=args.frames, window=args.window)
     for row in rows:
         print(",".join(f"{k}={v}" for k, v in row.items()))
     write_json(payload, args.json)
-    print(f"# device-resident hot path: {payload['speedup']:.2f}x wall "
-          f"frames/sec ({payload['wall_fps_sync']:.0f} -> "
-          f"{payload['wall_fps_fused']:.0f}); host syncs/flush "
-          f"{payload['host_syncs_per_flush_sync']:.1f} -> "
+    print(f"# device-resident hot path: host syncs/flush "
           f"{payload['host_syncs_per_flush_fused']:.1f}; classify FLOPs "
           f"saved {payload['classify_flops_saved_frac']:.0%}")
     print(f"# wrote {args.json}")
-    if args.quick:
-        print("# smoke mode: machinery + bit-identity verified")
-        return
-    if payload["speedup"] < 2.0:
-        print(f"# FAIL: expected >=2x wall-clock e2e frames/sec at "
-              f"{args.streams} streams, got {payload['speedup']:.2f}x",
-              file=sys.stderr)
-        raise SystemExit(1)
     if payload["host_syncs_per_flush_fused"] > 1.0 + 1e-9:
-        print("# FAIL: fused path must hold ONE host sync per flush, got "
+        print("# FAIL: the hot path must hold ONE host sync per flush, got "
               f"{payload['host_syncs_per_flush_fused']:.2f}",
               file=sys.stderr)
         raise SystemExit(1)
-    print(f"# PASS: >=2x end-to-end wall throughput at {args.streams} "
-          "streams, one host sync per flush")
+    print("# PASS: one host sync per flush")
 
 
 if __name__ == "__main__":

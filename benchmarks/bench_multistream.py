@@ -11,12 +11,11 @@ Measures frames/sec through the jit'd cloud-detector stage in two modes:
 Also asserts single-stream graph execution is numerically identical to the
 sequential protocol path (the refactor's safety property).
 
-Both sides run ``hot_path="sync"``: this benchmark isolates the PR-1
-cross-stream *batching* lever (call-overhead amortization of the bare
-detect dispatch), so it keeps the pre-fusion stage structure it was
-calibrated on.  The PR-4 fused hot path folds the compute-bound split into
-the timed stage — its end-to-end payoff is gated separately in
-``bench_e2e_throughput.py``.
+Both sides run the one served path, so the timed stage is the same fused
+``cloud.detect_split`` dispatch plus its one validity-mask read in both
+modes.  Only how many chunks one call carries differs, so the ratio still
+isolates the cross-stream *batching* lever: the per-call overhead that
+packing concurrent chunks amortizes.
 
 Usage:
   PYTHONPATH=src python benchmarks/bench_multistream.py             # full
@@ -64,8 +63,7 @@ def _run_sequential(det_params, clf_params, streams):
     stats = {"frames": 0, "wall_s": 0.0, "calls": 0}
     for chunks in streams:
         coord = CloudFogCoordinator(HighLowProtocol(BENCH_DET, BENCH_CLF),
-                                    det_params, clf_params,
-                                    hot_path="sync")
+                                    det_params, clf_params)
         coord.run(chunks, learn=False)
         d = coord.scheduler.detect_stats
         stats["frames"] += d["frames"]
@@ -81,8 +79,7 @@ def _run_concurrent(det_params, clf_params, streams, *, max_batch, window,
     multi = MultiStreamCoordinator(HighLowProtocol(BENCH_DET, BENCH_CLF),
                                    det_params, clf_params, streams,
                                    max_batch_chunks=max_batch,
-                                   batch_window=window, autoscaler=scaler,
-                                   hot_path="sync")
+                                   batch_window=window, autoscaler=scaler)
     multi.run(learn=False)
     rep = multi.report()
     if scaler is not None:

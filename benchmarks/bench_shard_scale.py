@@ -91,7 +91,7 @@ def _one_point(graph, clf_params, n_streams: int, *, rounds: int,
         graph, num_shards=shards, store=store,
         batcher_factory=lambda i: CrossStreamBatcher(
             max_chunks=max_batch_chunks, window=window),
-        hot_path="fused", crop_buckets=CROP_BUCKETS,
+        crop_buckets=CROP_BUCKETS,
         # replica pool grows with the fleet (constant per-stream service
         # capacity across the sweep); p2c routing engages at 3+ replicas
         cloud_replicas=shards,

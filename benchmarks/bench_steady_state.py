@@ -84,7 +84,7 @@ def bench(n_streams: int = 64, duration_s: float = 60.0, frames: int = 8,
         graph,
         batcher=CrossStreamBatcher(max_chunks=max_batch_chunks,
                                    window=window),
-        hot_path="fused", crop_buckets=CROP_BUCKETS,
+        crop_buckets=CROP_BUCKETS,
         max_retained_bundles=max_retained_bundles)
     pools = _chunk_pool(n_streams, frames)
     states = [sched.add_stream(f"cam{i:03d}", W=clf_params["W"])

@@ -113,7 +113,7 @@ def _run_fleet(graph, clf_params, *, rounds: int, frames: int,
         if cost_aware else dict(cloud_replicas=MAX_REPLICAS)
     sched = GraphScheduler(
         graph, batcher=CrossStreamBatcher(max_chunks=8, window=0.05),
-        hot_path="fused", cost_model=cost,
+        cost_model=cost,
         store=ArtifactStore(ttl=5.0, capacity_bytes=64e6), **kw)
     ten = Tenancy(graph, cost)
     states = []
@@ -147,8 +147,7 @@ def _bitwise_check(graph, clf_params, *, rounds: int, frames: int) -> bool:
         sched.run_until_idle()
 
     plain = GraphScheduler(
-        graph, batcher=CrossStreamBatcher(max_chunks=8, window=0.05),
-        hot_path="fused")
+        graph, batcher=CrossStreamBatcher(max_chunks=8, window=0.05))
     sa = [plain.add_stream(f"cam{i}", W=clf_params["W"], slo=GOLD_B.slo_s)
           for i in range(4)]
     drive(plain, sa)
@@ -156,7 +155,7 @@ def _bitwise_check(graph, clf_params, *, rounds: int, frames: int) -> bool:
     spec = TenantSpec("vision", GOLD_B, weight=1.0)
     tenanted = GraphScheduler(
         graph, batcher=CrossStreamBatcher(max_chunks=8, window=0.05),
-        hot_path="fused", cost_model=CostModel())
+        cost_model=CostModel())
     sb = [tenanted.add_stream(f"cam{i}", W=clf_params["W"],
                               slo=GOLD_B.slo_s, tenant=spec)
           for i in range(4)]

@@ -8,8 +8,8 @@ seeds and synthetic traffic made from a seed.  It never reads
 ``artifacts/``.
 
   phase 1  16 streams x 4 chunks x 8 frames through MultiStreamCoordinator
-           (hot_path="fused", cloud_replicas=2, a chunk-latency SLO that
-           makes deadline-driven multi-request flushes).  Halfway through,
+           (cloud_replicas=2, a chunk-latency SLO that makes
+           deadline-driven multi-request flushes).  Halfway through,
            one GraphScheduler.hot_swap installs a readout that
            incremental.batch_update computed on the chip from the labelled
            fog features served so far.  Every finalized chunk is checked
@@ -238,8 +238,7 @@ def serve(pcfg, det_params, clf_params, streams, *, swap_after: int,
              for i, chunks in enumerate(streams)]
     multi = MultiStreamCoordinator(
         HighLowProtocol(DETECTOR, CLASSIFIER, pcfg), det_params, clf_params,
-        specs, max_batch_chunks=MAX_BATCH_CHUNKS, cloud_replicas=2,
-        hot_path="fused")
+        specs, max_batch_chunks=MAX_BATCH_CHUNKS, cloud_replicas=2)
     sched = multi.scheduler
     states = [sched.streams[s.name] for s in specs]
     for spec, st in zip(specs, states):

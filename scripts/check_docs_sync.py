@@ -89,7 +89,7 @@ def collect():
         warm_pool=WarmPoolPolicy(cold_start_s=0.5, max_replicas=3))
     sched = GraphScheduler(
         graph, batcher=CrossStreamBatcher(max_chunks=4, window=0.05),
-        hot_path="fused", cost_model=cost,
+        cost_model=cost,
         # 1-byte capacity forces spills so the spill cost keys are live
         store=ArtifactStore(ttl=5.0, capacity_bytes=1.0),
         autoscaler=autoscaler, scale_unit="replicas", cold_start_s=0.5,

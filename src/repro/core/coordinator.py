@@ -84,7 +84,7 @@ class CloudFogCoordinator:
                  learner: IncrementalLearner = None,
                  annotator: OracleAnnotator = None,
                  network: NetworkModel = None, monitor: Monitor = None,
-                 hot_path: str = "fused", learning_plane=None):
+                 learning_plane=None):
         self.protocol = protocol
         self.det_params = det_params
         self.clf_params = clf_params
@@ -99,7 +99,6 @@ class CloudFogCoordinator:
         self.scheduler = GraphScheduler(
             self.graph, network=self.network, monitor=self.monitor,
             batcher=CrossStreamBatcher(max_chunks=1, window=0.0),
-            hot_path=hot_path,
             fault=self.fault, fallback_fn=self._fog_fallback)
         self.plane = learning_plane
         if learning_plane is not None:
@@ -197,7 +196,6 @@ class MultiStreamCoordinator:
                  adaptive_margin: bool = True,
                  cold_start_s: float = 0.0,
                  scale_unit: Optional[str] = None,
-                 hot_path: str = "fused",
                  autoscaler=None, fault: FaultTolerantCoordinator = None,
                  learning_plane=None, num_shards: int = 1,
                  use_store: bool = False):
@@ -218,7 +216,6 @@ class MultiStreamCoordinator:
             autoscaler=autoscaler, scale_unit=scale_unit,
             deadline_batching=deadline_batching,
             adaptive_margin=adaptive_margin, cold_start_s=cold_start_s,
-            hot_path=hot_path,
             fault=fault, fallback_fn=self._fog_fallback)
         if num_shards > 1 or use_store:
             # thousand-stream mode: K per-shard event loops + claim-check

@@ -14,37 +14,39 @@ One chunk flows client -> fog -> cloud -> fog:
      cloud cost — RQ2), dynamic batching included,
   5. crops + predictions are queued for the §V HITL loop.
 
-Each hop is a separately jit'd **stage function** so the serving layer can
-dispatch them as independent serverless functions (``repro.serving.graph``):
+Each hop is a separately jit'd **stage function**.  The serving layer
+(``repro.serving.graph``) dispatches the fused ones as serverless
+functions, so tensors stay on the device end to end:
 
-  ``encode_low``        fog quality control        (fog.encode_low)
-  ``detect_regions``    heavy cloud detector       (cloud.detect) — batchable
-                        across concurrent streams along the frame axis
-  ``split_uncertain``   §IV.B three-stage filter   (cloud side of detect)
-  ``classify_regions``  HQ crop + one-vs-all merge (fog.classify_regions)
-
-The serving hot path additionally fuses stages so tensors stay on device
-end-to-end (``repro.serving.graph`` with ``hot_path="fused"``):
-
-  ``detect_split``        detect + split in ONE jit call over the packed
-                          cross-stream batch (cloud.detect_split) — per-chunk
-                          coord bytes / crop counts come back as arrays, so
-                          the scheduler needs one host transfer per flush
+  ``encode_low``          fog quality control (fog.encode_low)
+  ``detect_split``        detect + §IV.B split in ONE jit call over the
+                          packed cross-stream batch (cloud.detect_split) —
+                          per-chunk coord bytes / crop counts come back as
+                          arrays, so the scheduler needs one host transfer
+                          per flush
   ``classify_compacted``  gathers only the valid proposals of the whole
                           flush into one bucketed crop batch, classifies
                           cross-stream with per-stream readouts, and
                           scatters scores back (fog.classify_batched)
 
-``HighLowProtocol.process_chunk`` drives the unfused stage functions
-strictly sequentially — the single-stream reference path.  The fused path
-is bit-identical to it: splitting a packed batch then slicing equals
-slicing then splitting (per-frame vmap), and the compacted classifier's
-crop stage shares one fixed-lowering bilinear program with the full-grid
-path (``impl="ref"`` materializes the grid then gathers; kernel impls run
-the Pallas ``crop_gather`` over just the bucket rows — same bits either
-way), feeding a backbone whose per-row outputs are batch-composition-
-independent.  Orchestration (bytes, latency, cost accounting) happens at
-the stage boundaries.
+The unfused stages are the **one-chunk reference** that tests, baselines
+and ``chip_smoke.py`` compare the served path against:
+
+  ``detect_regions``      the heavy cloud detector
+  ``split_uncertain``     §IV.B three-stage filter
+  ``classify_regions``    HQ crop of every region slot + one-vs-all merge
+  ``classify_ensemble``   the same, scored by an Eq. (9) snapshot ensemble
+
+``HighLowProtocol.process_chunk`` drives them strictly sequentially on one
+chunk.  The served path computes the same function: splitting a packed
+batch then slicing equals slicing then splitting (per-frame vmap), and the
+compacted classifier's crop stage runs the bilinear program of the
+full-grid path over just the bucket rows, feeding a backbone whose
+per-row outputs do not depend on the batch.  The two are different XLA
+programs, so their floats may differ in the last bits (a compiler may
+contract multiply-adds differently in each fusion); tests hold them to a
+stated tolerance.  Orchestration (bytes, latency, cost accounting) happens
+at the stage boundaries.
 """
 from __future__ import annotations
 
@@ -112,19 +114,19 @@ def encode_low(pcfg: ProtocolConfig, frames_hq: jax.Array) -> codec.EncodedChunk
 @functools.partial(jax.jit, static_argnames=("det_cfg",))
 def detect_regions(det_cfg: DetectorConfig, det_params,
                    frames: jax.Array) -> Dict[str, jax.Array]:
-    """cloud.detect — the heavy detector on LOW-quality frames.
+    """Reference: the heavy cloud detector on LOW-quality frames.
 
-    The leading axis is a plain frame batch: frames from *multiple
-    concurrent streams* may be concatenated (and zero-padded to a bucket)
-    into one call; per-frame outputs are independent, so callers slice the
-    result back apart."""
+    The leading axis is a plain frame batch; per-frame outputs are
+    independent.  The served path runs the detector inside
+    :func:`detect_split`."""
     return det_mod.detect(det_cfg, det_params, frames)
 
 
 @functools.partial(jax.jit, static_argnames=("pcfg",))
 def split_uncertain(pcfg: ProtocolConfig, det: Dict[str, jax.Array]
                     ) -> Tuple[reg.RegionSplit, jax.Array]:
-    """cloud side of detect — §IV.B split into accepted vs uncertain."""
+    """Reference: the §IV.B split into accepted vs uncertain regions, and
+    the coordinate bytes the cloud sends back."""
     split = reg.split_regions(
         det, theta_cls=pcfg.theta_cls, theta_loc=pcfg.theta_loc,
         theta_iou=pcfg.theta_iou, theta_back=pcfg.theta_back, impl=pcfg.impl)
@@ -139,11 +141,10 @@ def detect_split(det_cfg: DetectorConfig, pcfg: ProtocolConfig, det_params,
     Takes the packed cross-stream frame batch and returns the full-batch
     :class:`~repro.core.regions.RegionSplit`.  Both the split filter and
     the detector are per-frame independent, so slicing the fused output per
-    chunk is bit-identical to running ``split_uncertain`` on each chunk's
-    detector slice — but the scheduler issues ONE jit call and needs one
-    host transfer (the validity mask, from which per-chunk coord bytes and
-    crop counts are derived) instead of O(chunks) calls and scalar syncs
-    per flush."""
+    chunk computes what ``detect_regions`` + ``split_uncertain`` (the
+    reference) compute on that chunk — but the scheduler issues ONE jit
+    call and needs one host transfer (the validity mask, from which
+    per-chunk coord bytes and crop counts are derived) per flush."""
     det = det_mod.detect(det_cfg, det_params, frames)
     return reg.split_regions(
         det, theta_cls=pcfg.theta_cls, theta_loc=pcfg.theta_loc,
@@ -210,12 +211,12 @@ def _merge_fog(pcfg: ProtocolConfig, split: reg.RegionSplit,
 def classify_regions(clf_cfg: ClassifierConfig, pcfg: ProtocolConfig,
                      clf_params, W, frames_hq: jax.Array,
                      split: reg.RegionSplit) -> Dict[str, jax.Array]:
-    """fog.classify_regions — HQ crop + one-vs-all classify + merge.
+    """Reference: HQ crop + one-vs-all classify + merge for one stream.
 
-    The full-budget reference path: every region slot in the F x N grid is
-    cropped and classified.  Outputs at invalid proposal positions are
-    masked to zero so the compacted path (which never computes them)
-    scatters into an identical result."""
+    Every region slot in the F x N grid is cropped and classified.  Outputs
+    at invalid proposal positions are masked to zero, as the compacted
+    path (which never computes them) leaves them.  The served path runs
+    :func:`classify_compacted`."""
     crops = reg.crop_batch(frames_hq, split.prop_boxes, clf_cfg.crop_hw)
     f, n = crops.shape[0], crops.shape[1]
     flat = crops.reshape(f * n, *crops.shape[2:])
@@ -277,15 +278,14 @@ def classify_ensemble(clf_cfg: ClassifierConfig, pcfg: ProtocolConfig,
                       clf_params, snaps: jax.Array, omega: jax.Array,
                       frames_hq: jax.Array, split: reg.RegionSplit
                       ) -> Dict[str, jax.Array]:
-    """fog.classify_ensemble — Eq. (9) snapshot-ensemble classify + merge.
+    """Reference: Eq. (9) snapshot-ensemble classify + merge for one stream.
 
-    The full-budget single-stream stage: every region slot is cropped, one
-    backbone pass feeds all T stacked snapshots, and the per-crop score is
-    the omega-weighted sigmoid combination.  With one snapshot and
-    omega=[1.0] the output is bitwise-identical to
-    :func:`classify_regions` — the multi-readout stage *contains* the
-    single-readout stage as its degenerate case, so serving can switch a
-    stream between them without a numerics boundary."""
+    Every region slot is cropped, one backbone pass feeds all T stacked
+    snapshots, and the per-crop score is the omega-weighted sigmoid
+    combination.  With one snapshot and omega=[1.0] the output is
+    bitwise-identical to :func:`classify_regions` — the multi-readout
+    stage *contains* the single-readout stage as its degenerate case.  The
+    served path runs :func:`classify_compacted_ensemble`."""
     crops = reg.crop_batch(frames_hq, split.prop_boxes, clf_cfg.crop_hw)
     f, n = crops.shape[0], crops.shape[1]
     flat = crops.reshape(f * n, *crops.shape[2:])
@@ -328,7 +328,7 @@ def assemble_result(split: reg.RegionSplit, merged: Dict[str, jax.Array],
                     *, wan_bytes: float, coord_bytes: float,
                     cloud_frames: int, latency: LatencyBreakdown
                     ) -> ChunkResult:
-    """Shared result assembly for the sequential and graph execution paths."""
+    """Host-side :class:`ChunkResult` of one chunk's split and merge."""
     return ChunkResult(
         boxes=np.asarray(merged["boxes"]), labels=np.asarray(merged["labels"]),
         valid=np.asarray(merged["valid"]), source=np.asarray(merged["source"]),

@@ -152,8 +152,7 @@ def serve_video(args) -> None:
         specs, max_batch_chunks=args.video_streams,
         batch_window=args.video_window,
         cloud_replicas=args.video_replicas, autoscaler=scaler,
-        cold_start_s=args.video_cold_start,
-        hot_path=args.video_hot_path, learning_plane=plane)
+        cold_start_s=args.video_cold_start, learning_plane=plane)
     t0 = time.time()
     out = multi.run(learn=args.learning)
     dt = time.time() - t0
@@ -170,10 +169,6 @@ def serve_video(args) -> None:
     print(f"  batching: up to {rep['batch_max_batch_chunks']} chunks/call "
           f"({rep['batch_deadline_flushes']:.0f} deadline-driven); "
           f"autoscaler {scaler.summary()}")
-    print(f"  hot path: {rep['hot_path']} — "
-          f"{rep.get('host_syncs_per_flush', 0):.1f} host syncs/flush, "
-          f"classify FLOPs saved {rep.get('classify_flops_saved_frac', 0):.0%}, "
-          f"in-flight result peak {rep.get('hot_inflight_peak', 0)}")
     if args.video_slo:
         mon = multi.scheduler.monitor
         print(f"  SLO {args.video_slo*1e3:.0f} ms: attainment "
@@ -231,12 +226,6 @@ def main() -> None:
     ap.add_argument("--video-cold-start", type=float, default=0.0,
                     help="serverless container spin-up seconds for replicas "
                          "added by the autoscaler")
-    ap.add_argument("--video-hot-path", default="fused",
-                    choices=("fused", "sync"),
-                    help="'fused' = device-resident hot path (one fused "
-                         "detect+split dispatch and one host sync per "
-                         "flush, compacted cross-stream classify); 'sync' "
-                         "= the pre-fusion baseline for A/B comparison")
     ap.add_argument("--learning", action="store_true",
                     help="attach the continual-learning plane (drift "
                          "detection, budgeted labeling, background "

@@ -239,42 +239,19 @@ class CrossStreamBatcher:
         return len(self._queue)
 
 
-def pack_frames(frame_arrays: List[np.ndarray],
-                buckets: Tuple[int, ...] = (2, 4, 8, 16, 32, 64)
-                ) -> Tuple[np.ndarray, List[slice], int]:
-    """Concatenate per-chunk frame arrays into one batch along axis 0.
-
-    Multi-chunk batches are zero-padded up to the next bucket size so the
-    jit'd detector sees few distinct shapes; a single request passes through
-    exactly as-is (no padding), keeping the sequential path bit-identical.
-    Returns (batch, per-request slices, padded_frames)."""
-    assert frame_arrays, "pack_frames needs at least one request"
-    slices, off = [], 0
-    for a in frame_arrays:
-        slices.append(slice(off, off + a.shape[0]))
-        off += a.shape[0]
-    batch = np.concatenate([np.asarray(a) for a in frame_arrays], axis=0)
-    pad = 0
-    if len(frame_arrays) > 1:
-        size = next((b for b in buckets if off <= b), None)
-        size = off if size is None else size
-        pad = size - off
-        if pad:
-            batch = np.concatenate(
-                [batch, np.zeros((pad,) + batch.shape[1:], batch.dtype)], 0)
-    return batch, slices, pad
-
-
 def pack_frames_device(frame_arrays: List[Any],
                        buckets: Tuple[int, ...] = (2, 4, 8, 16, 32, 64)
                        ) -> Tuple[Any, List[slice], int]:
-    """Device-side twin of :func:`pack_frames`: concat + zero-pad as lazy
-    jnp ops, so per-chunk frames that are already device-resident (the
-    ``encode_low`` output) are packed without a device->host->device round
-    trip.  Same bucket/slice semantics; a single request passes through
-    exactly as-is (the bit-identical sequential path — the array object
-    itself, so not even a copy is queued).  Returns
-    (batch, per-request slices, padded_frames)."""
+    """Concatenate per-chunk frame arrays into one batch along axis 0.
+
+    Concat + zero-pad run as lazy jnp ops, so per-chunk frames that are
+    already device-resident (the ``encode_low`` output) are packed without
+    a device->host->device round trip.  Multi-chunk batches are zero-padded
+    up to the next bucket size so the jit'd detector sees few distinct
+    shapes (past the largest bucket: the exact size, nothing truncated); a
+    single request passes through exactly as-is (the array object itself,
+    so not even a copy is queued), keeping the sequential path
+    bit-identical.  Returns (batch, per-request slices, padded_frames)."""
     assert frame_arrays, "pack_frames_device needs at least one request"
     slices, off = [], 0
     for a in frame_arrays:

@@ -8,8 +8,10 @@ names and dispatched through :class:`~repro.serving.executor.Executor` /
 :class:`~repro.serving.router.Router`:
 
   ``fog.encode_low``        quality control on the per-camera fog node
-  ``cloud.detect``          heavy detector — **batched across streams**
-  ``fog.classify_regions``  HQ crop + one-vs-all classify + merge
+  ``cloud.detect_split``    heavy detector + §IV.B split — **batched
+                            across streams**
+  ``fog.classify_batched``  HQ crop + one-vs-all classify + merge, over
+                            the flush's valid proposals
   ``hitl.collect``          §V feedback collection + incremental update
 
 Execution is **event-driven**: a priority queue of per-stream events
@@ -35,29 +37,27 @@ replicas, the autoscaler can add/remove whole replicas
 (``scale_unit="replicas"``), and a replica that dies mid-run has its
 sub-batch re-queued to survivors (or the fog fallback) with no chunk lost.
 
-The default ``hot_path="fused"`` keeps the detect->split->classify dataflow
-**device-resident**: ``encode_low`` output never round-trips through numpy,
-cross-stream packing is a device-side concat+pad, the cloud stage is the
-fused ``cloud.detect_split`` (one jit dispatch and **one** blocking
-device->host read — the proposal-validity mask — per flush, instead of a
-``block_until_ready`` plus two scalar syncs per chunk), the fog stage is
-the compacted ``fog.classify_batched`` (only the flush's valid proposals
-are gathered into one bucketed crop batch and classified cross-stream with
+The detect->split->classify dataflow stays **device-resident**:
+``encode_low`` output never round-trips through numpy, cross-stream packing
+is a device-side concat+pad, the cloud stage is the fused
+``cloud.detect_split`` (one jit dispatch and **one** blocking device->host
+read — the proposal-validity mask — per flush), the fog stage is the
+compacted ``fog.classify_batched`` (only the flush's valid proposals are
+gathered into one bucketed crop batch and classified cross-stream with
 per-stream readouts, scattered back into the full result grid), per-stream
 readouts are uploaded once and refreshed only on hot-swap/learner update,
 and chunk results stay device-side futures queued in ``_inflight`` until
 their finalize event drains them — so flush k's detect overlaps flush
-k-1's host-side result materialization.  ``hot_path="sync"`` preserves the
-pre-fusion synchronous path (the benchmark baseline).  Both paths are
-bit-identical to ``HighLowProtocol.process_chunk`` on a single stream.
+k-1's host-side result materialization.
 
-With one stream and a zero batching window the event order degenerates to
-the strict sequential path, and because the stage functions agree
-bit-for-bit, results are identical to ``HighLowProtocol.process_chunk``.
+The reference is ``HighLowProtocol.process_chunk``, which runs the unfused
+stage functions on one chunk: tests hold every served chunk to it at a
+stated float tolerance.  With one stream and a zero batching window the
+served path runs the same programs on the same shapes and agrees with it
+bit for bit.
 """
 from __future__ import annotations
 
-import dataclasses
 import heapq
 import itertools
 import sys
@@ -75,7 +75,7 @@ from repro.core.bandwidth import LatencyBreakdown, NetworkModel
 from repro.core.hitl import OracleAnnotator
 from repro.core.protocol import ChunkResult, HighLowProtocol
 from repro.serving.batching import (CrossStreamBatcher, DetectRequest,
-                                    pack_frames, pack_frames_device)
+                                    pack_frames_device)
 from repro.serving.executor import Executor
 from repro.serving.ingest import (ArtifactCorrupted, ArtifactStore,
                                   ClaimCheck, content_key)
@@ -86,20 +86,24 @@ from repro.serving.spans import SpanRecorder
 from repro.serving.tenancy import TenantChunkResult
 
 STAGE_ENCODE = "fog.encode_low"
-STAGE_DETECT = "cloud.detect"
 STAGE_DETECT_SPLIT = "cloud.detect_split"      # fused detect + §IV.B split
 STAGE_DETECT_SPLIT_DON = "cloud.detect_split_donated"  # donates the batch
 STAGE_DETECT_SPLIT_DYN = "cloud.detect_split_dynamic"  # per-frame thetas
-STAGE_CLASSIFY = "fog.classify_regions"
 STAGE_CLASSIFY_BATCH = "fog.classify_batched"  # compacted cross-stream
-STAGE_CLASSIFY_ENS = "fog.classify_ensemble"   # Eq. 9 snapshot ensemble
-STAGE_CLASSIFY_ENS_BATCH = "fog.classify_ensemble_batched"
+STAGE_CLASSIFY_ENS_BATCH = "fog.classify_ensemble_batched"  # Eq. 9
 STAGE_CLASSIFY_VIEW = "fog.classify_view"      # per-stream slice accounting
 STAGE_COLLECT = "hitl.collect"
-STAGES = (STAGE_ENCODE, STAGE_DETECT, STAGE_DETECT_SPLIT,
-          STAGE_DETECT_SPLIT_DON, STAGE_DETECT_SPLIT_DYN, STAGE_CLASSIFY,
-          STAGE_CLASSIFY_BATCH, STAGE_CLASSIFY_ENS, STAGE_CLASSIFY_ENS_BATCH,
-          STAGE_CLASSIFY_VIEW, STAGE_COLLECT)
+STAGES = (STAGE_ENCODE, STAGE_DETECT_SPLIT, STAGE_DETECT_SPLIT_DON,
+          STAGE_DETECT_SPLIT_DYN, STAGE_CLASSIFY_BATCH,
+          STAGE_CLASSIFY_ENS_BATCH, STAGE_CLASSIFY_VIEW, STAGE_COLLECT)
+
+# adaptive SLO margin: each stream's margin tracks an EWMA (weight
+# MARGIN_ALPHA) of its observed deadline attainment between MARGIN_BOUNDS
+MARGIN_BOUNDS = (0.05, 0.5)
+MARGIN_ALPHA = 0.25
+# a replica counts as straggling when its observed service rate predicts
+# more than (1 + HEDGE_SLACK) x the nominal service time
+HEDGE_SLACK = 0.1
 
 
 # ---------------------------------------------------------------------------
@@ -118,8 +122,6 @@ class VideoFunctionGraph:
         p = self.protocol
         self.registry.register(STAGE_ENCODE, self._encode, kind="preprocess",
                                tier="fog")
-        self.registry.register(STAGE_DETECT, self._detect, kind="inference",
-                               tier="cloud", batchable=True)
         self.registry.register(STAGE_DETECT_SPLIT, self._detect_split,
                                kind="inference", tier="cloud",
                                batchable=True, fused=True)
@@ -131,12 +133,8 @@ class VideoFunctionGraph:
                                self._detect_split_dynamic,
                                kind="inference", tier="cloud",
                                batchable=True, fused=True)
-        self.registry.register(STAGE_CLASSIFY, self._classify,
-                               kind="inference", tier="fog")
         self.registry.register(STAGE_CLASSIFY_BATCH, self._classify_batched,
                                kind="inference", tier="fog", batchable=True)
-        self.registry.register(STAGE_CLASSIFY_ENS, self._classify_ensemble,
-                               kind="inference", tier="fog", ensemble=True)
         self.registry.register(STAGE_CLASSIFY_ENS_BATCH,
                                self._classify_ensemble_batched,
                                kind="inference", tier="fog", batchable=True,
@@ -150,24 +148,19 @@ class VideoFunctionGraph:
         self.zoo.register("cloud-detector", self.det_params, p.det_cfg)
         self.zoo.register("fog-classifier", self.clf_params, p.clf_cfg)
         self.dispatcher = Dispatcher(self.registry, self.zoo)
-        self.dispatcher.dispatch("cloud", STAGE_DETECT)
         self.dispatcher.dispatch("cloud", STAGE_DETECT_SPLIT)
         self.dispatcher.dispatch("cloud", STAGE_DETECT_SPLIT_DON)
         self.dispatcher.dispatch("cloud", STAGE_DETECT_SPLIT_DYN)
         self.dispatcher.dispatch("cloud", "cloud-detector")
-        for name in (STAGE_ENCODE, STAGE_CLASSIFY, STAGE_CLASSIFY_BATCH,
-                     STAGE_CLASSIFY_ENS, STAGE_CLASSIFY_ENS_BATCH,
-                     STAGE_CLASSIFY_VIEW, STAGE_COLLECT, "fog-classifier"):
+        for name in (STAGE_ENCODE, STAGE_CLASSIFY_BATCH,
+                     STAGE_CLASSIFY_ENS_BATCH, STAGE_CLASSIFY_VIEW,
+                     STAGE_COLLECT, "fog-classifier"):
             self.dispatcher.dispatch("fog", name)
 
     # -- stage callables (close over configs/params) ------------------------
     def _encode(self, frames_hq):
         return protocol_mod.encode_low(self.protocol.pcfg,
                                        jnp.asarray(frames_hq))
-
-    def _detect(self, frames):
-        return protocol_mod.detect_regions(self.protocol.det_cfg,
-                                           self.det_params, frames)
 
     def _detect_split(self, frames):
         return protocol_mod.detect_split(self.protocol.det_cfg,
@@ -188,16 +181,6 @@ class VideoFunctionGraph:
         return protocol_mod.classify_compacted(
             self.protocol.clf_cfg, self.protocol.pcfg, self.clf_params, Ws,
             frames_hq, split, idxs)
-
-    def _classify(self, frames_hq, split, W):
-        return protocol_mod.classify_regions(
-            self.protocol.clf_cfg, self.protocol.pcfg, self.clf_params, W,
-            frames_hq, split)
-
-    def _classify_ensemble(self, frames_hq, split, snaps, omega):
-        return protocol_mod.classify_ensemble(
-            self.protocol.clf_cfg, self.protocol.pcfg, self.clf_params,
-            snaps, omega, frames_hq, split)
 
     def _classify_ensemble_batched(self, frames_hq, split, snaps, omegas,
                                    idxs):
@@ -460,23 +443,23 @@ class GraphScheduler:
                  autoscaler=None, scale_unit: str = "devices",
                  deadline_batching: bool = True, slo_margin: float = 0.1,
                  adaptive_margin: bool = True,
-                 margin_bounds: Tuple[float, float] = (0.05, 0.5),
-                 margin_alpha: float = 0.25,
                  cold_start_s: float = 0.0,
                  hot_path: str = "fused",
                  crop_buckets: Tuple[int, ...] = (4, 8, 16, 32, 64, 128),
                  max_retained_bundles: Optional[int] = 256,
                  fault=None, fallback_fn: Optional[Callable] = None,
-                 hedging: bool = True, hedge_slack: float = 0.1,
+                 hedging: bool = True,
                  router: Optional[Router] = None,
                  seq_counter=None,
                  store: Optional[ArtifactStore] = None,
                  pick_policy: str = "least",
                  cost_model=None,
-                 fog_queueing: bool = False,
                  hitl_cost_s: float = 0.0,
                  warm_pool=None):
-        assert hot_path in ("fused", "sync")
+        if hot_path != "fused":
+            # the one served path; the keyword stays only so that
+            # configurations that name it keep working
+            raise ValueError(f"hot_path must be 'fused', got {hot_path!r}")
         proto = graph.protocol
         self.graph = graph
         self.network = network or proto.network
@@ -519,11 +502,9 @@ class GraphScheduler:
         # carry error, and a batch held open to the exact deadline misses
         # on any slip.  ``slo_margin`` is each stream's *initial* margin;
         # with ``adaptive_margin`` it then tracks an EWMA of the stream's
-        # observed deadline attainment between ``margin_bounds``.
+        # observed deadline attainment between ``MARGIN_BOUNDS``.
         self.slo_margin = slo_margin
         self.adaptive_margin = adaptive_margin
-        self.margin_bounds = margin_bounds
-        self.margin_alpha = margin_alpha
         # continual-learning plane hook (ContinualLearningPlane.attach)
         self.plane = None
         self.fault = fault
@@ -537,7 +518,6 @@ class GraphScheduler:
         # decision is gated on an attached fault schedule, so a fault-free
         # or idle-injector run never hedges and stays bitwise-identical.
         self.hedging = hedging
-        self.hedge_slack = hedge_slack
         # flapped-replica readmission: health probes with exponential
         # backoff, only for outages the injector marks transient
         self.probe_base = 0.05
@@ -585,12 +565,9 @@ class GraphScheduler:
         # replica retired by scale-down takes its ExecutionRecords with it
         self._detect_windows: List[Tuple[float, float]] = []
         # --- device-resident hot path -------------------------------------
-        # "fused": one cloud.detect_split dispatch + ONE blocking host read
-        # (the validity mask) per flush, compacted cross-stream classify,
-        # results kept as device futures until their finalize event.
-        # "sync": the pre-fusion baseline (per-chunk split + scalar syncs +
-        # full-budget classify + block_until_ready) for A/B benchmarking.
-        self.hot_path = hot_path
+        # one cloud.detect_split dispatch + ONE blocking host read (the
+        # validity mask) per flush, compacted cross-stream classify, results
+        # kept as device futures until their finalize event
         self.crop_buckets = crop_buckets
         # donate the packed detect batch to the fused jit on accelerator
         # backends: the multi-request concat buffer is dispatch-owned and
@@ -598,8 +575,7 @@ class GraphScheduler:
         # donation a warning-level no-op, so CI keeps the plain stage; the
         # single-request pass-through (an encode-output / store-held array)
         # is never donated regardless of the flag.
-        self.donate_detect = (hot_path == "fused"
-                              and jax.default_backend() != "cpu")
+        self.donate_detect = jax.default_backend() != "cpu"
         # shared executor for the compacted cross-stream classify call (the
         # per-stream share is accounted on each stream's own fog executor)
         self.fog_batch_exec = Executor("fog-batch", graph.registry, proto.fog)
@@ -636,24 +612,22 @@ class GraphScheduler:
         # copies kept) so device residency stays flat.  ``None`` disables.
         self.max_retained_bundles = max_retained_bundles
         self._bundles: Deque[_FlushBundle] = deque()
-        # per-field result download counts (fused path): the lazy-bundle
-        # regression ledger — a HITL-off run must show zero fog_features /
-        # fog_scores downloads here
+        # per-field result download counts: the lazy-bundle regression
+        # ledger — a HITL-off run must show zero fog_features / fog_scores
+        # downloads here
         self.field_downloads: Dict[str, int] = {}
         # --- tenancy (tenancy.py) ------------------------------------------
         # cost_model: per-tenant monetary metering.  Pure accounting — it
         # never moves an event time, so attaching one leaves the schedule
-        # bitwise-identical.  fog_queueing (opt-in) folds a stream's real
-        # fog-executor queueing delay into its reported latency instead of
-        # the pre-tenancy instantaneous-accounting convention.  hitl_cost_s
-        # prices HITL collect work on the fog node's *background* lane
-        # (Executor priority="background"), where it can never head-of-line
-        # block the stream's own serving work.
+        # bitwise-identical.  A chunk's reported latency counts no wait
+        # for its stream's fog executor (instantaneous fog accounting).
+        # hitl_cost_s prices HITL collect work on the fog node's
+        # *background* lane (Executor priority="background"), where it can
+        # never head-of-line block the stream's own serving work.
         self.cost_model = cost_model
         if cost_model is not None:
             self.router.cost_model = cost_model
             cost_model.observe_pool(0.0, self.router.healthy_count())
-        self.fog_queueing = fog_queueing
         self.hitl_cost_s = hitl_cost_s
         # --- warm-pool management plane (autoscaler.WarmPoolPolicy) --------
         # every arrival feeds the policy's per-tenant forecasters; the
@@ -677,7 +651,7 @@ class GraphScheduler:
                    weight: float = 1.0, tenant=None) -> StreamState:
         fog_exec = Executor(f"fog-{name}", self.graph.registry,
                             self.graph.protocol.fog)
-        lo, hi = self.margin_bounds
+        lo, hi = MARGIN_BOUNDS
         att0 = 1.0 - (min(max(self.slo_margin, lo), hi) - lo) / max(hi - lo,
                                                                     1e-9)
         st = StreamState(name=name, W=np.asarray(W), fog_exec=fog_exec,
@@ -832,8 +806,7 @@ class GraphScheduler:
                 wan_bytes = float(enc.nbytes)
             wan_up = self.network.wan_time(wan_bytes, t=t)
             arrival = t + qc + wan_up
-            frames = (enc.frames if self.hot_path == "fused"
-                      else np.asarray(enc.frames))
+            frames = enc.frames
             if self.store is not None:
                 # claim-check publish: the encoded frames enter the
                 # artifact store once (content-addressed — a pooled chunk
@@ -875,8 +848,7 @@ class GraphScheduler:
         cached key is salt-checked so one chunk shared across schedulers
         with different encode configs never aliases."""
         pcfg = self.graph.protocol.pcfg
-        salt = (f"{pcfg.r_low}:{pcfg.q_low}:{int(pcfg.inter_coding)}:"
-                f"{self.hot_path}")
+        salt = f"{pcfg.r_low}:{pcfg.q_low}:{int(pcfg.inter_coding)}"
         cached = getattr(chunk, "_artifact_key", None)
         if cached is not None and cached[0] == salt:
             return cached[1]
@@ -1021,7 +993,6 @@ class GraphScheduler:
                     self._schedule_probe(uid, t)
                     continue
                 break
-            fused = self.hot_path == "fused"
             # claim-check resolve: flush assembly is the ONE place payloads
             # are pulled from the store.  A single-request flush passes the
             # stored array object straight through pack_frames_device,
@@ -1031,13 +1002,8 @@ class GraphScheduler:
                     payloads = [self._resolve_payload(r, t) for r in reqs]
                 else:
                     payloads = [r.frames for r in reqs]
-                if fused:
-                    batch, slices, pad = pack_frames_device(
-                        payloads, buckets=self.batcher.pad_buckets)
-                else:
-                    batch, slices, pad = pack_frames(
-                        [np.asarray(p) for p in payloads],
-                        buckets=self.batcher.pad_buckets)
+                batch, slices, pad = pack_frames_device(
+                    payloads, buckets=self.batcher.pad_buckets)
             n_frames = batch.shape[0]
             svc = proto.cloud.detect_time(n_frames)
             rep = self.router.replicas[idx]
@@ -1099,19 +1065,13 @@ class GraphScheduler:
             if (self.hedging and self.fault is not None
                     and deadline is not None and rep.rate_ewma is not None):
                 est_svc = rep.rate_ewma * n_frames
-                if (est_svc > svc * (1.0 + self.hedge_slack)
+                if (est_svc > svc * (1.0 + HEDGE_SLACK)
                         and est_start + est_svc > deadline):
                     hedge = self._pick_hedge(t, idx, svc, n_frames,
                                              est_start + est_svc)
             self.hot_path_stats["flushes"] += 1
-            if fused:
-                self._dispatch_fused(t, reqs, slices, pad, batch, svc_eff,
-                                     idx, queue_depth, timeout, hedge,
-                                     flush=flush)
-            else:
-                self._dispatch_sync(t, reqs, slices, pad, batch, svc_eff,
-                                    idx, queue_depth, timeout, hedge,
-                                    flush=flush)
+            self._dispatch_fused(t, reqs, slices, pad, batch, svc_eff, idx,
+                                 queue_depth, timeout, hedge, flush=flush)
             # observed per-frame service rate feeds the next hedge decision;
             # one-dispatch lag is the realistic detector dynamic (a straggler
             # is spotted by its first slow completion, then hedged around)
@@ -1134,9 +1094,7 @@ class GraphScheduler:
         except ArtifactCorrupted:
             self._count_h2d(req.meta["chunk"].frames)
             enc = self.graph._encode(req.meta["chunk"].frames)
-            fresh = (enc.frames if self.hot_path == "fused"
-                     else np.asarray(enc.frames))
-            self.store.repair(req.frames.key, fresh)
+            self.store.repair(req.frames.key, enc.frames)
             self.chaos_stats["corruptions_repaired"] += 1
             self.monitor.log_event("artifact_repair", t=t,
                                    key=req.frames.key)
@@ -1168,7 +1126,7 @@ class GraphScheduler:
                 continue
             est_rate = (r.rate_ewma if r.rate_ewma is not None
                         else svc / max(n_frames, 1))
-            if est_rate * n_frames > svc * (1.0 + self.hedge_slack):
+            if est_rate * n_frames > svc * (1.0 + HEDGE_SLACK):
                 continue                     # known straggler itself
             est_done = start + est_rate * n_frames
             if est_done >= primary_est_done - 1e-12:
@@ -1286,108 +1244,6 @@ class GraphScheduler:
             if self.router.healthy_count() < cur:
                 self.warm_stats["shed_events"] += 1
         self._schedule_warm_check(t)
-
-    def _dispatch_sync(self, t: float, reqs: List[DetectRequest], slices,
-                       pad: int, batch, svc: float, idx: int,
-                       queue_depth: int, timeout: Optional[float] = None,
-                       hedge=None, *, flush: int) -> None:
-        """Pre-fusion baseline: blocking detect, one ``split_uncertain``
-        jit call plus two scalar device syncs per chunk, full-budget
-        classify, immediate result materialization."""
-        proto = self.graph.protocol
-        n_frames = batch.shape[0]
-        with self._span("vpaas.detect", flush=flush):
-            det, done, svc_w, h_billed = self._route_detect(
-                STAGE_DETECT, (jnp.asarray(batch),), t=t, svc=svc, idx=idx,
-                queue_depth=queue_depth, timeout=timeout, hedge=hedge)
-            with self._span("vpaas.wait.detect", flush=flush):
-                jax.block_until_ready(det)
-            self.hot_path_stats["host_syncs"] += 1
-            self.detect_stats["calls"] += 1
-            self.detect_stats["frames"] += n_frames - pad
-            self.detect_stats["padded_frames"] += pad
-        start = done - svc_w
-
-        for req, sl in zip(reqs, slices):
-            det_i = {k: v[sl] for k, v in det.items()}
-            pcfg_req = proto.pcfg
-            if (req.stream.theta_cls is not None
-                    or req.stream.theta_loc is not None):
-                # per-site thresholds: a frozen-config replace stays
-                # hashable, so the handful of distinct per-site configs
-                # each compile split_uncertain once
-                pcfg_req = dataclasses.replace(
-                    pcfg_req,
-                    theta_cls=(req.stream.theta_cls
-                               if req.stream.theta_cls is not None
-                               else pcfg_req.theta_cls),
-                    theta_loc=(req.stream.theta_loc
-                               if req.stream.theta_loc is not None
-                               else pcfg_req.theta_loc))
-            split, coord_bytes = protocol_mod.split_uncertain(pcfg_req,
-                                                              det_i)
-            with self._span("vpaas.wait.prop_valid", flush=flush):
-                coord_bytes = float(coord_bytes)
-                pv, av = jax.device_get((split.prop_valid, split.acc_valid))
-                n_crops = int(np.sum(pv))
-            self.detect_stats["nms_rounds"] += reg.nms_rounds(av, pv)
-            wan_down = self.network.wan_time(coord_bytes, t=done)
-            self.hot_path_stats["host_syncs"] += 2   # the two scalar reads
-            clf_time = proto.fog.classify_time(max(n_crops, 1))
-            obs = wan_down + clf_time
-            self._downstream_est = (obs if obs > self._downstream_est
-                                    else 0.9 * self._downstream_est
-                                    + 0.1 * obs)
-            stream = req.stream
-            chunk = req.meta["chunk"]
-            self.hot_path_stats["crops_classified"] += split.prop_valid.size
-            self.hot_path_stats["crops_budget"] += split.prop_valid.size
-            if stream.ensemble is not None:
-                snaps_dev, omega_dev = stream.ensemble_device()
-                merged, done_c = stream.fog_exec.run(
-                    STAGE_CLASSIFY_ENS, jnp.asarray(chunk.frames), split,
-                    snaps_dev, omega_dev, now=done + wan_down,
-                    model_time=clf_time)
-            else:
-                merged, done_c = stream.fog_exec.run(
-                    STAGE_CLASSIFY, jnp.asarray(chunk.frames), split,
-                    jnp.asarray(stream.W), now=done + wan_down,
-                    model_time=clf_time)
-            # fog_queueing: the wait for the stream's fog device (busy with
-            # an earlier chunk) joins the reported latency; default keeps
-            # the pre-tenancy instantaneous-accounting convention
-            fog_wait = (max(0.0, done_c - clf_time - (done + wan_down))
-                        if self.fog_queueing else 0.0)
-            if self.cost_model is not None:
-                f = req.frames.shape[0]
-                tname = self._tenant_name(stream)
-                self.cost_model.charge_cloud(
-                    tname, frames=f, invocations=f,
-                    busy_s=svc * f / max(n_frames - pad, 1), t=t)
-                if h_billed is not None:
-                    # a hedge is a real invocation: its duplicate device
-                    # time lands in the tenant's ledger either way the
-                    # race resolves
-                    self.cost_model.charge_hedge(
-                        tname, invocations=f,
-                        busy_s=h_billed * f / max(n_frames - pad, 1), t=t)
-                self.cost_model.charge_fog(tname, clf_time, t)
-            lat = LatencyBreakdown(
-                quality_control=req.meta["qc"],
-                transmission=req.meta["wan_up"] + wan_down,
-                cloud_inference=svc_w,
-                fog_inference=clf_time,
-                queue_wait=max(0.0, start - req.arrival) + fog_wait)
-            with self._span("vpaas.wait.result_fields", flush=flush):
-                res = protocol_mod.assemble_result(
-                    split, merged, wan_bytes=req.meta["wan_bytes"],
-                    coord_bytes=coord_bytes,
-                    cloud_frames=req.frames.shape[0], latency=lat)
-            self.hot_path_stats["host_syncs"] += 1   # eager materialization
-            self._push(req.meta["t0"] + lat.total, "finalize",
-                       dict(stream=stream, chunk=chunk, res=res,
-                            mode="cloud", learn=req.meta["learn"],
-                            t0=req.meta["t0"]))
 
     def _dispatch_fused(self, t: float, reqs: List[DetectRequest], slices,
                         pad: int, batch, svc: float, idx: int,
@@ -1546,12 +1402,10 @@ class GraphScheduler:
                 chunk = req.meta["chunk"]
                 # the stream's share of the batched classify: pure
                 # accounting on its own fog node's clock (the compute
-                # already ran batched)
-                _, done_c = stream.fog_exec.run(STAGE_CLASSIFY_VIEW, sl,
-                                                now=done + wan_down,
-                                                model_time=clf_time)
-                fog_wait = (max(0.0, done_c - clf_time - (done + wan_down))
-                            if self.fog_queueing else 0.0)
+                # already ran batched); the node's busy horizon gates when
+                # its background HITL work may start
+                stream.fog_exec.run(STAGE_CLASSIFY_VIEW, sl,
+                                    now=done + wan_down, model_time=clf_time)
                 if self.cost_model is not None:
                     f = req.frames.shape[0]
                     tname = self._tenant_name(stream)
@@ -1571,7 +1425,7 @@ class GraphScheduler:
                     transmission=req.meta["wan_up"] + wan_down,
                     cloud_inference=svc_w,
                     fog_inference=clf_time,
-                    queue_wait=max(0.0, start - req.arrival) + fog_wait)
+                    queue_wait=max(0.0, start - req.arrival))
                 res = LazyChunkResult(
                     bundle, sl, wan_bytes=req.meta["wan_bytes"],
                     coord_bytes=coord_bytes,
@@ -1646,17 +1500,15 @@ class GraphScheduler:
                 coord_bytes = float(getattr(out_sl, "nbytes", 8 * f))
                 wan_down = self.network.wan_time(coord_bytes, t=done)
                 fog_time = f / pipe.fog_fps
-                result, done_c = stream.fog_exec.run(
+                result, _ = stream.fog_exec.run(
                     pipe.fog_stage, chunk.frames, out_sl,
                     now=done + wan_down, model_time=fog_time)
-                fog_wait = (max(0.0, done_c - fog_time - (done + wan_down))
-                            if self.fog_queueing else 0.0)
                 lat = LatencyBreakdown(
                     quality_control=req.meta["qc"],
                     transmission=req.meta["wan_up"] + wan_down,
                     cloud_inference=svc,
                     fog_inference=fog_time,
-                    queue_wait=max(0.0, start - req.arrival) + fog_wait)
+                    queue_wait=max(0.0, start - req.arrival))
                 billed = pipe.billed(result, f)
                 if self.cost_model is not None:
                     tname = self._tenant_name(stream)
@@ -1716,10 +1568,10 @@ class GraphScheduler:
                 self.monitor.record("slo_margin",
                                     stream.slo - res.latency.total, t0)
                 if self.adaptive_margin:
-                    a = self.margin_alpha
+                    a = MARGIN_ALPHA
                     stream.att_ewma = ((1.0 - a) * stream.att_ewma
                                        + a * (1.0 if met else 0.0))
-                    lo, hi = self.margin_bounds
+                    lo, hi = MARGIN_BOUNDS
                     stream.slo_margin = (lo + (hi - lo)
                                          * (1.0 - stream.att_ewma))
             if (self.plane is None and data["learn"]
@@ -1801,7 +1653,7 @@ class GraphScheduler:
         self._ens_cache[key] = (srcs, out)
         while len(self._ens_cache) > self._ens_cache_cap:
             self._ens_cache.pop(next(iter(self._ens_cache)))
-        # upload-regression ledger for the fused path: recurring flush
+        # upload-regression ledger: recurring flush
         # mixes should hit the memo — a climbing count means cache thrash
         self.hot_path_stats["ensemble_uploads"] += 1
         return out
@@ -1847,7 +1699,7 @@ class GraphScheduler:
         that threshold (the bit-compatible state).  Chunks already past
         their detect dispatch keep the thresholds they ran with; the next
         flush containing this stream routes through the dynamic fused
-        stage (or a per-site config replace on the sync path)."""
+        stage."""
         st = self.streams[stream]
         st.theta_cls = theta_cls
         st.theta_loc = theta_loc
@@ -1862,11 +1714,10 @@ class GraphScheduler:
         """Swap an Eq. 9 snapshot ensemble into live serving.
 
         The stream's classify stage switches to the multi-readout
-        ``fog.classify_ensemble`` / ``fog.classify_ensemble_batched``
-        variant scoring against the whole lineage; ``W`` (the latest
-        promoted readout) is untouched — the learning plane keeps using it
-        to rescore label candidates.  Same zero-loss semantics as
-        :meth:`hot_swap`."""
+        ``fog.classify_ensemble_batched`` variant scoring against the whole
+        lineage; ``W`` (the latest promoted readout) is untouched — the
+        learning plane keeps using it to rescore label candidates.  Same
+        zero-loss semantics as :meth:`hot_swap`."""
         snaps = np.asarray(snaps)
         omega = np.asarray(omega)
         targets = self._swap_targets(stream)
@@ -1890,7 +1741,6 @@ class GraphScheduler:
         d.update({f"batch_{k}": v for k, v in self.batcher.stats.items()})
         d["replicas"] = len(self.router.replicas)
         d["healthy_replicas"] = self.router.healthy_count()
-        d["hot_path"] = self.hot_path
         hps = self.hot_path_stats
         d.update({f"hot_{k}": v for k, v in hps.items()})
         if hps["flushes"]:

@@ -71,9 +71,9 @@ _MAX_KEYS = frozenset((
 # "cost", "tenants") must not be summed K times.  The router is shared
 # too, so its timeout counter reports once; per-shard chaos_* counters
 # (hedges, probes, requeues, repairs) sum through the default branch.
-_FIRST_KEYS = frozenset(("hot_path", "replicas", "healthy_replicas",
-                         "peak_devices", "peak_queue", "store_spills",
-                         "cost", "tenants", "chaos_route_timeouts"))
+_FIRST_KEYS = frozenset(("replicas", "healthy_replicas", "peak_devices",
+                         "peak_queue", "store_spills", "cost", "tenants",
+                         "chaos_route_timeouts"))
 
 
 class ShardedScheduler:

@@ -54,7 +54,6 @@ def _chunks(seed, n, frames=2):
 
 def _sched(graph, **kw):
     kw.setdefault("batcher", CrossStreamBatcher(max_chunks=4, window=0.05))
-    kw.setdefault("hot_path", "fused")
     return GraphScheduler(graph, **kw)
 
 
@@ -203,7 +202,7 @@ def test_idle_injector_identity_sharded(models):
             graph, num_shards=2, store=ArtifactStore(integrity=True),
             batcher_factory=lambda i: CrossStreamBatcher(max_chunks=4,
                                                          window=0.05),
-            hot_path="fused", cloud_replicas=2, fault=fault)
+            cloud_replicas=2, fault=fault)
         return sched, _run(sched, sched.add_stream, streams, clf_params,
                            slo=0.5)
 

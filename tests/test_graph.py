@@ -19,7 +19,7 @@ from repro.models import classifier as clf_mod
 from repro.models import detector as det_mod
 from repro.serving.autoscaler import Autoscaler
 from repro.serving.batching import (CrossStreamBatcher, DetectRequest,
-                                    pack_frames)
+                                    pack_frames_device)
 from repro.serving.fault import FaultTolerantCoordinator
 from repro.serving.graph import STAGES, VideoFunctionGraph
 
@@ -56,27 +56,25 @@ def test_graph_registers_stages_and_models(models):
                                clf_params)
     for name in STAGES:
         assert name in graph.registry
-    assert graph.registry.entry("cloud.detect").metadata["tier"] == "cloud"
-    assert graph.registry.entry("cloud.detect").metadata["batchable"]
+    assert graph.registry.entry(
+        "cloud.detect_split").metadata["tier"] == "cloud"
+    assert graph.registry.entry("cloud.detect_split").metadata["batchable"]
     assert graph.registry.entry("fog.encode_low").kind == "preprocess"
     assert graph.registry.list(kind="inference") == [
-        "cloud.detect", "cloud.detect_split", "cloud.detect_split_donated",
+        "cloud.detect_split", "cloud.detect_split_donated",
         "cloud.detect_split_dynamic", "fog.classify_batched",
-        "fog.classify_ensemble", "fog.classify_ensemble_batched",
-        "fog.classify_regions"]
+        "fog.classify_ensemble_batched"]
     # the fused cloud stage and the compacted fog stage are both batchable
     assert graph.registry.entry("cloud.detect_split").metadata["fused"]
     assert graph.registry.entry("fog.classify_batched").metadata["batchable"]
-    # the Eq. 9 stages are flagged as multi-readout ensemble variants
-    assert graph.registry.entry("fog.classify_ensemble").metadata["ensemble"]
+    # the Eq. 9 stage is flagged as the multi-readout ensemble variant
+    assert graph.registry.entry(
+        "fog.classify_ensemble_batched").metadata["ensemble"]
     assert graph.registry.entry(
         "fog.classify_ensemble_batched").metadata["batchable"]
     assert "cloud-detector" in graph.zoo and "fog-classifier" in graph.zoo
-    assert "cloud.detect" in graph.dispatcher.deployed("cloud")
     assert "cloud.detect_split" in graph.dispatcher.deployed("cloud")
-    assert "fog.classify_regions" in graph.dispatcher.deployed("fog")
     assert "fog.classify_batched" in graph.dispatcher.deployed("fog")
-    assert "fog.classify_ensemble" in graph.dispatcher.deployed("fog")
     assert "fog.classify_ensemble_batched" in graph.dispatcher.deployed("fog")
 
 
@@ -297,22 +295,25 @@ def test_pack_frames_padding_semantics():
     a = np.random.rand(2, 8, 8, 3).astype(np.float32)
     b = np.random.rand(3, 8, 8, 3).astype(np.float32)
     # single request: exact shape, no padding (bit-identical fast path)
-    batch, slices, pad = pack_frames([a])
+    batch, slices, pad = pack_frames_device([a])
     assert batch.shape[0] == 2 and pad == 0
-    np.testing.assert_array_equal(batch, a)
+    np.testing.assert_array_equal(np.asarray(batch), a)
     # multi request: concatenated then zero-padded to the next bucket
-    batch, slices, pad = pack_frames([a, b], buckets=(2, 4, 8))
+    batch, slices, pad = pack_frames_device([a, b], buckets=(2, 4, 8))
     assert batch.shape[0] == 8 and pad == 3
-    np.testing.assert_array_equal(batch[slices[0]], a)
-    np.testing.assert_array_equal(batch[slices[1]], b)
-    assert not batch[5:].any()
+    np.testing.assert_array_equal(
+        np.asarray(batch),
+        np.concatenate([a, b, np.zeros((3, 8, 8, 3), np.float32)]))
+    np.testing.assert_array_equal(np.asarray(batch[slices[0]]), a)
+    np.testing.assert_array_equal(np.asarray(batch[slices[1]]), b)
     # overflow past the largest bucket: exact concatenated size, no padding
     # (and no truncation — every frame must reach the detector)
     big = [np.random.rand(3, 8, 8, 3).astype(np.float32) for _ in range(4)]
-    batch, slices, pad = pack_frames(big, buckets=(2, 4, 8))
+    batch, slices, pad = pack_frames_device(big, buckets=(2, 4, 8))
     assert batch.shape[0] == 12 and pad == 0
+    np.testing.assert_array_equal(np.asarray(batch), np.concatenate(big))
     for arr, sl in zip(big, slices):
-        np.testing.assert_array_equal(batch[sl], arr)
+        np.testing.assert_array_equal(np.asarray(batch[sl]), arr)
 
 
 # ---------------------------------------------------------------------------

@@ -16,6 +16,7 @@ from repro.learning import (BackgroundTrainer, ContinualLearningPlane,
                             ReplayBuffer, ShadowEvaluator)
 from repro.models import classifier as clf_mod
 from repro.models import detector as det_mod
+from repro.serving.graph import MARGIN_BOUNDS
 from repro.serving.registry import ModelZoo
 from repro.serving.router import Router
 
@@ -381,7 +382,7 @@ def test_adaptive_slo_margin_tracks_attainment(models):
     m0 = st.slo_margin
     multi.run(learn=False)
     assert st.slo_margin < m0
-    lo, hi = multi.scheduler.margin_bounds
+    lo, hi = MARGIN_BOUNDS
     assert lo <= st.slo_margin <= hi
 
     # opting out keeps the static headroom

@@ -5,6 +5,7 @@ replay the unsharded simulated timeline at small scale, stolen work is
 dispatched exactly once (even through a replica outage), and the artifact
 store never evicts a payload something still references.  All checks are
 execution semantics on untrained models — no accuracy, module stays fast."""
+import dataclasses
 import warnings
 
 import jax
@@ -22,6 +23,7 @@ from repro.serving.fault import FaultTolerantCoordinator
 from repro.serving.graph import GraphScheduler, VideoFunctionGraph
 from repro.serving.ingest import ArtifactStore, ClaimCheck, content_key
 from repro.serving.shards import ShardedScheduler
+from reference_check import assert_matches_reference
 
 DET = DetectorConfig(name="shard-test-det", image_hw=(32, 32),
                      widths=(8, 16))
@@ -101,15 +103,13 @@ def test_one_shard_bitwise_identity(models):
     streams = [_chunks(300 + i, 3) for i in range(4)]
 
     plain = GraphScheduler(
-        graph, batcher=CrossStreamBatcher(max_chunks=4, window=0.05),
-        hot_path="fused")
+        graph, batcher=CrossStreamBatcher(max_chunks=4, window=0.05))
     _run(plain, plain.add_stream, streams, clf_params)
 
     sharded = ShardedScheduler(
         graph, num_shards=1,
         batcher_factory=lambda i: CrossStreamBatcher(max_chunks=4,
-                                                     window=0.05),
-        hot_path="fused")
+                                                     window=0.05))
     _run(sharded, sharded.add_stream, streams, clf_params)
 
     for name in plain.streams:
@@ -134,12 +134,10 @@ def test_k_shards_match_unsharded_oracle(models, num_shards):
     # max_chunks=1 / window=0: batch composition cannot depend on the
     # partition, so the merged K-shard timeline must equal one scheduler's
     oracle = GraphScheduler(
-        graph, batcher=CrossStreamBatcher(max_chunks=1, window=0.0),
-        hot_path="fused")
+        graph, batcher=CrossStreamBatcher(max_chunks=1, window=0.0))
     _run(oracle, oracle.add_stream, streams, clf_params)
 
-    sharded = ShardedScheduler(graph, num_shards=num_shards, steal=False,
-                               hot_path="fused")
+    sharded = ShardedScheduler(graph, num_shards=num_shards, steal=False)
     _run(sharded, sharded.add_stream, streams, clf_params)
 
     for name in oracle.streams:
@@ -166,7 +164,7 @@ def test_work_stealing_conserves_chunks_under_outage(models):
         graph, num_shards=2,
         batcher_factory=lambda i: CrossStreamBatcher(max_chunks=2,
                                                      window=0.05),
-        hot_path="fused", cloud_replicas=2, fault=fault)
+        cloud_replicas=2, fault=fault)
     # pin every stream to shard 0: shard 1 exists only to have work stolen
     states = [sharded.add_stream(f"cam{i}", W=clf_params["W"], shard=0)
               for i in range(n_busy)]
@@ -235,8 +233,7 @@ def test_store_eviction_under_serving_load(models):
     base = _chunks(600, 2)
     streams = [[base[0], base[1], base[0], base[1]] for _ in range(2)]
     store = ArtifactStore(ttl=1e-6)
-    sharded = ShardedScheduler(graph, num_shards=1, store=store,
-                               hot_path="fused")
+    sharded = ShardedScheduler(graph, num_shards=1, store=store)
     _run(sharded, sharded.add_stream, streams, clf_params)
     for name, st in sharded.streams.items():
         assert len(st.results) == 4       # nothing dropped by eviction
@@ -248,27 +245,30 @@ def test_store_eviction_under_serving_load(models):
 # ---------------------------------------------------------------------------
 # per-site detector thresholds
 # ---------------------------------------------------------------------------
-def test_stream_thresholds_fused_matches_sync(models):
+def test_stream_thresholds_fused_matches_reference(models):
     graph, clf_params = _graph(models)
+    det_params, _ = models
     streams = [_chunks(700 + i, 2) for i in range(3)]
-    scheds = {}
-    for mode in ("sync", "fused"):
-        s = GraphScheduler(
-            graph, batcher=CrossStreamBatcher(max_chunks=3, window=0.05),
-            hot_path=mode)
-        states = [s.add_stream(f"cam{i}", W=clf_params["W"])
-                  for i in range(len(streams))]
-        # cam1 runs off-default thresholds; cam0/cam2 stay global — one
-        # fused flush mixes default and override frames
-        s.set_stream_thresholds("cam1", theta_cls=0.55, theta_loc=0.3)
-        for st, chunks in zip(states, streams):
-            for c in chunks:
-                s.submit(st, c, learn=False)
-        s.run_until_idle()
-        scheds[mode] = s
-    for name in scheds["sync"].streams:
-        _assert_results_bitwise(scheds["sync"].streams[name],
-                                scheds["fused"].streams[name])
+    s = GraphScheduler(
+        graph, batcher=CrossStreamBatcher(max_chunks=3, window=0.05))
+    states = [s.add_stream(f"cam{i}", W=clf_params["W"])
+              for i in range(len(streams))]
+    # cam1 runs off-default thresholds; cam0/cam2 stay global — one
+    # fused flush mixes default and override frames
+    s.set_stream_thresholds("cam1", theta_cls=0.55, theta_loc=0.3)
+    for st, chunks in zip(states, streams):
+        for c in chunks:
+            s.submit(st, c, learn=False)
+    s.run_until_idle()
+    proto = graph.protocol
+    site = HighLowProtocol(DET, CLF, pcfg=dataclasses.replace(
+        proto.pcfg, theta_cls=0.55, theta_loc=0.3))
+    for name, st in s.streams.items():
+        ref_proto = site if name == "cam1" else proto
+        assert len(st.results) == 2
+        for chunk, res, _ in st.results:
+            assert_matches_reference(res, ref_proto.process_chunk(
+                det_params, clf_params, chunk.frames, W=st.W))
 
 
 def test_stream_thresholds_defaults_bit_compatible(models):
@@ -276,15 +276,13 @@ def test_stream_thresholds_defaults_bit_compatible(models):
     pcfg = graph.protocol.pcfg
     streams = [_chunks(750 + i, 2) for i in range(2)]
     plain = GraphScheduler(
-        graph, batcher=CrossStreamBatcher(max_chunks=2, window=0.05),
-        hot_path="fused")
+        graph, batcher=CrossStreamBatcher(max_chunks=2, window=0.05))
     _run(plain, plain.add_stream, streams, clf_params)
 
     # explicitly pinning the global defaults routes through the dynamic
     # stage but must reproduce the static stage bit-for-bit
     pinned = GraphScheduler(
-        graph, batcher=CrossStreamBatcher(max_chunks=2, window=0.05),
-        hot_path="fused")
+        graph, batcher=CrossStreamBatcher(max_chunks=2, window=0.05))
     states = [pinned.add_stream(f"cam{i}", W=clf_params["W"])
               for i in range(len(streams))]
     for i in range(len(streams)):
@@ -305,8 +303,7 @@ def test_stream_thresholds_defaults_bit_compatible(models):
 def test_plane_adapts_thresholds_on_drift_episode(models):
     graph, clf_params = _graph(models)
     sched = GraphScheduler(
-        graph, batcher=CrossStreamBatcher(max_chunks=1, window=0.0),
-        hot_path="fused")
+        graph, batcher=CrossStreamBatcher(max_chunks=1, window=0.0))
     sched.add_stream("cam0", W=clf_params["W"])
     plane = ContinualLearningPlane(
         CLF.num_classes, LearningConfig(adapt_theta_cls=0.4,
@@ -333,13 +330,11 @@ def test_donated_detect_bitwise_on_cpu(models):
     graph, clf_params = _graph(models)
     streams = [_chunks(800 + i, 2) for i in range(3)]
     plain = GraphScheduler(
-        graph, batcher=CrossStreamBatcher(max_chunks=3, window=0.05),
-        hot_path="fused")
+        graph, batcher=CrossStreamBatcher(max_chunks=3, window=0.05))
     _run(plain, plain.add_stream, streams, clf_params)
 
     donating = GraphScheduler(
-        graph, batcher=CrossStreamBatcher(max_chunks=3, window=0.05),
-        hot_path="fused")
+        graph, batcher=CrossStreamBatcher(max_chunks=3, window=0.05))
     donating.donate_detect = True    # forced on: CPU warns and ignores it
     with warnings.catch_warnings():
         warnings.simplefilter("ignore")

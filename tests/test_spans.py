@@ -151,8 +151,7 @@ def fused_run():
     rng = np.random.default_rng(3)
     sched = GraphScheduler(
         VideoFunctionGraph(HighLowProtocol(DET, CLF), det_params, clf_params),
-        batcher=CrossStreamBatcher(max_chunks=4, window=0.05),
-        hot_path="fused")
+        batcher=CrossStreamBatcher(max_chunks=4, window=0.05))
     sched.plane = _TouchResults()
     chunks = []
     for i in range(4):

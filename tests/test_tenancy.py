@@ -72,8 +72,7 @@ def test_default_path_bitwise_identity(models):
     streams = [_chunks(700 + i, 3) for i in range(4)]
 
     plain = GraphScheduler(
-        graph, batcher=CrossStreamBatcher(max_chunks=4, window=0.05),
-        hot_path="fused")
+        graph, batcher=CrossStreamBatcher(max_chunks=4, window=0.05))
     sa = [plain.add_stream(f"cam{i}", W=clf_params["W"], slo=5.0)
           for i in range(4)]
     _drain(plain, sa, streams)
@@ -83,7 +82,7 @@ def test_default_path_bitwise_identity(models):
     spec = TenantSpec("vision", GOLD, weight=1.0)
     tenant = GraphScheduler(
         graph, batcher=CrossStreamBatcher(max_chunks=4, window=0.05),
-        hot_path="fused", cost_model=CostModel())
+        cost_model=CostModel())
     sb = [tenant.add_stream(f"cam{i}", W=clf_params["W"], slo=5.0,
                             tenant=spec) for i in range(4)]
     _drain(tenant, sb, streams)
@@ -118,7 +117,7 @@ def test_cost_ledger_conservation(models):
     cost = CostModel()
     sched = GraphScheduler(
         graph, batcher=CrossStreamBatcher(max_chunks=4, window=0.05),
-        hot_path="fused", cost_model=cost,
+        cost_model=cost,
         store=ArtifactStore(ttl=5.0, capacity_bytes=1.0))
     ten = Tenancy(graph, cost)
     ten.register(TenantSpec("vision", GOLD, weight=4.0))
@@ -163,7 +162,7 @@ def test_wfq_share_conservation_under_overload(models):
     cost = CostModel()
     sched = GraphScheduler(
         graph, batcher=CrossStreamBatcher(max_chunks=1, window=10.0),
-        hot_path="fused", cost_model=cost, deadline_batching=False)
+        cost_model=cost, deadline_batching=False)
     heavy = TenantSpec("heavy", BRONZE, weight=3.0)
     light = TenantSpec("light", BRONZE, weight=1.0)
     shared = _chunks(900, 8)
@@ -218,7 +217,7 @@ def test_hitl_cost_never_delays_chunks(models):
     def run(hitl_cost_s):
         sched = GraphScheduler(
             graph, batcher=CrossStreamBatcher(max_chunks=2, window=0.05),
-            hot_path="fused", hitl_cost_s=hitl_cost_s)
+            hitl_cost_s=hitl_cost_s)
         st = sched.add_stream(
             "cam0", W=clf_params["W"],
             learner=IncrementalLearner(num_classes=CLF.num_classes,
@@ -281,7 +280,7 @@ def test_store_spills_surface_in_throughput_report(models):
     # spills as soon as the next publish lands
     sched = GraphScheduler(
         graph, batcher=CrossStreamBatcher(max_chunks=1, window=0.0),
-        hot_path="fused", store=ArtifactStore(ttl=100.0, capacity_bytes=1.0))
+        store=ArtifactStore(ttl=100.0, capacity_bytes=1.0))
     st = sched.add_stream("cam0", W=clf_params["W"])
     for c in _chunks(920, 3):
         sched.submit(st, c, learn=False)
@@ -326,7 +325,7 @@ def test_tenant_pipelines_share_fleet_sharded(models):
         graph, num_shards=2,
         batcher_factory=lambda i: CrossStreamBatcher(max_chunks=4,
                                                      window=0.05),
-        hot_path="fused", cost_model=cost)
+        cost_model=cost)
     ten = Tenancy(graph, cost)
     ten.register(TenantSpec("vision", GOLD, weight=4.0))
     ten.register(TenantSpec("cascade", SILVER, weight=2.0,

@@ -275,7 +275,7 @@ def test_scheduler_prewarms_ahead_and_sheds_after_bursts(models):
                               cold_start_s=0.6, warm_pool=pol)
     sched = GraphScheduler(
         graph, batcher=CrossStreamBatcher(max_chunks=8, window=0.05),
-        hot_path="fused", cost_model=cost, cloud_replicas=1,
+        cost_model=cost, cloud_replicas=1,
         autoscaler=asc, scale_unit="replicas", cold_start_s=0.6,
         warm_pool=pol)
     states = [sched.add_stream(f"cam{i}", W=clf_params["W"], slo=5.0)
@@ -340,7 +340,7 @@ def test_disabled_policy_is_bitwise_identical(models, num_shards):
             graph, num_shards=num_shards,
             batcher_factory=lambda i: CrossStreamBatcher(max_chunks=8,
                                                          window=0.05),
-            use_store=False, hot_path="fused", cloud_replicas=2,
+            use_store=False, cloud_replicas=2,
             warm_pool=warm_pool)
         states = [sched.add_stream(f"cam{i}", W=clf_params["W"], slo=5.0)
                   for i in range(4)]
@@ -374,7 +374,7 @@ def test_prewarmed_replica_survives_injected_flap(models):
                               cold_start_s=0.6, warm_pool=pol)
     sched = GraphScheduler(
         graph, batcher=CrossStreamBatcher(max_chunks=8, window=0.05),
-        hot_path="fused", cloud_replicas=1, autoscaler=asc,
+        cloud_replicas=1, autoscaler=asc,
         scale_unit="replicas", cold_start_s=0.6, warm_pool=pol,
         fault=fi)
     states = [sched.add_stream(f"cam{i}", W=clf_params["W"], slo=5.0)
