@@ -88,8 +88,10 @@ def _video_stage_rows(quick: bool):
 
     # crop stage in isolation: full-grid materialize-then-gather (the
     # structure the kernel replaces: the F x N crop grid committed as a
-    # device intermediate, then indexed) vs the crop_gather program that
-    # only ever touches the B bucket rows.  The scaling claim is measured,
+    # device intermediate, then indexed) vs the served crop_gather program
+    # that only ever touches the B bucket rows (the Pallas kernel on a TPU,
+    # the oracle elsewhere: the row's "impl" names which ran).  The scaling
+    # claim is measured,
     # not asserted: B is held at one bucket while F x N grows 8x, so the
     # grid time climbs and the kernel time does not.  (The baseline is two
     # dispatches on purpose — in a single jitted program XLA *may* elide
@@ -134,6 +136,7 @@ def _video_stage_rows(quick: bool):
                      "full_grid_us": f"{us_grid:.0f}",
                      "crop_speedup": f"{us_grid / max(us_gath, 1e-9):.2f}",
                      "crops": f"{b_s}/{pv_s.size}",
+                     "impl": ops.crop_gather_impl("ref", frames_s.shape),
                      "note": "cost scales with B, not F x N"})
     return rows
 

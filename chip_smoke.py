@@ -9,7 +9,9 @@ seeds and synthetic traffic made from a seed.  It never reads
 
   phase 1  16 streams x 4 chunks x 8 frames through MultiStreamCoordinator
            (cloud_replicas=2, a chunk-latency SLO that makes
-           deadline-driven multi-request flushes).  Halfway through,
+           deadline-driven multi-request flushes; every flush crops its
+           regions with the Pallas crop_gather kernel, which the crop
+           step picks on a TPU whatever ``impl`` says).  Halfway through,
            one GraphScheduler.hot_swap installs a readout that
            incremental.batch_update computed on the chip from the labelled
            fog features served so far.  Every finalized chunk is checked
@@ -17,8 +19,8 @@ seeds and synthetic traffic made from a seed.  It never reads
            (impl="ref", matmul precision "highest", the readout that served
            the chunk).
   phase 2  the same run with ProtocolConfig(impl="pallas") -- the Pallas
-           region-filter and crop-gather kernels on the served path --
-           checked against phase 1.
+           region-filter kernel on the served path too -- checked against
+           phase 1.
 
 Usage:  python chip_smoke.py
 
@@ -296,6 +298,28 @@ def reference(det_params, clf_params, chunk, W):
     return res, {k: np.asarray(v) for k, v in det.items()}
 
 
+def classify_holds_kernel(pcfg, det_params, clf_params, chunk) -> bool:
+    """Whether the compacted classify stage, compiled for one chunk's
+    flush, holds a Mosaic (Pallas TPU) call.  On ``impl="ref"`` the crop
+    kernel is the only one the stage can hold."""
+    import jax.numpy as jnp
+
+    from repro.configs.vpaas_video import CLASSIFIER, DETECTOR
+    from repro.core import protocol as pm
+    from repro.core import regions as reg
+    frames = jnp.asarray(chunk.frames)
+    split = pm.detect_split(DETECTOR, pcfg, det_params,
+                            pm.encode_low(pcfg, frames).frames)
+    fidx, ridx, _, bucket = reg.compaction_indices(
+        np.asarray(split.prop_valid))
+    idxs = np.zeros((3, bucket), np.int32)
+    idxs[0], idxs[1] = fidx, ridx
+    compiled = pm.classify_compacted.lower(
+        CLASSIFIER, pcfg, clf_params, jnp.asarray(clf_params["W"])[None],
+        frames, split, jnp.asarray(idxs)).compile()
+    return "tpu_custom_call" in compiled.as_text()
+
+
 def peak_bytes(device) -> str:
     stats = device.memory_stats() or {}
     return str(stats.get("peak_bytes_in_use", "not reported"))
@@ -321,7 +345,8 @@ def run_phase(name, pcfg, det_params, clf_params, streams, device, *,
         f"({clock.seconds:.1f} s compiling), {rep['calls']} flushes "
         f"(up to {rep['batch_max_batch_chunks']} chunks, "
         f"{rep['batch_deadline_flushes']:.0f} deadline-driven), "
-        f"{rep['hot_crops_classified']} crops classified, peak_bytes_in_use "
+        f"{rep['hot_crops_classified']} crops classified "
+        f"({rep['hot_crops_kernel']} by the crop kernel), peak_bytes_in_use "
         f"{peak_bytes(device)}")
     log(f"[{name}] donation: {rep['donated_flushes']} flushes ran "
         f"detect_split_donated, XLA {'could not use' if unusable else 'used'}"
@@ -369,6 +394,12 @@ def run() -> bool:
     check_deviations(check1)
     if rep["donated_flushes"] == 0:
         check1.fail("no flush ran detect_split_donated")
+    if rep["hot_crops_kernel"] != rep["hot_crops_classified"]:
+        check1.fail(f"the crop kernel made {rep['hot_crops_kernel']} of "
+                    f"{rep['hot_crops_classified']} crops")
+    if not classify_holds_kernel(pcfg, det_params, clf_params,
+                                 streams[0][0]):
+        check1.fail("the compiled classify stage holds no Mosaic call")
     if not rep["swapped_mid_run"]:
         check1.fail("the hot swap did not land mid-run")
     if np.array_equal(new_W, np.asarray(clf_params["W"])):

@@ -82,6 +82,10 @@ class ProtocolConfig:
     fog_min_conf: float = 0.5
     # closed-loop inter-frame coding (H.264-faithful temporal compression)
     inter_coding: bool = True
+    # kernel implementation ("ref" / "pallas" / "interpret", kernels/ops.py)
+    # of the region filter and the one-vs-all head; the crop step picks by
+    # backend instead (``ops.crop_gather_impl``: the Pallas kernel on a TPU
+    # for "ref" and "pallas", the oracle elsewhere)
     impl: str = "ref"
 
 
@@ -230,19 +234,38 @@ def classify_regions(clf_cfg: ClassifierConfig, pcfg: ProtocolConfig,
     return _merge_fog(pcfg, split, fog_scores, fog_feats)
 
 
+# The implementation each compacted classify stage traced its crop step
+# onto, by (``impl``, HQ frames shape, bucket): what
+# ``ops.crop_gather_impl`` chose when the stage was traced, and so what its
+# compiled program runs.  The scheduler counts kernel crops from it.
+CROP_IMPL_TRACED: Dict[Tuple[str, Tuple[int, ...], int], str] = {}
+
+
+def crop_impl_traced(pcfg: ProtocolConfig, frames_shape, bucket: int
+                     ) -> Optional[str]:
+    """The crop implementation the stage traced for these shapes, or None
+    before the stage has been traced for them."""
+    return CROP_IMPL_TRACED.get((pcfg.impl, tuple(frames_shape), bucket))
+
+
 def _crop_bucket(clf_cfg: ClassifierConfig, pcfg: ProtocolConfig,
                  frames_hq: jax.Array, split: reg.RegionSplit,
                  idxs: jax.Array) -> jax.Array:
     """The compacted classify stages' crop step: (B, h, w, 3).
 
-    Crops only the B bucket rows that the gather plan ``idxs`` names, on
-    every ``impl``: ``ref`` through the jitted ``ref.crop_gather`` program,
-    kernel impls through the ``crop_gather`` Pallas kernel.  Both run the
-    bilinear program of ``ref.bilinear_crops``, so the rows are bitwise
-    what cropping the whole F x N grid and indexing it gives, pad rows
-    included, and the cost scales with B, not with F x N."""
+    Crops only the B bucket rows that the gather plan ``idxs`` names, with
+    what ``ops.crop_gather_impl`` picks by backend: the ``crop_gather``
+    Pallas kernel on a TPU, the jitted ``ref.crop_gather`` program
+    elsewhere (``impl="interpret"`` runs the kernel body, for tests), and
+    records the choice in ``CROP_IMPL_TRACED``.  Both run the bilinear
+    program of ``ref.bilinear_crops``, so the rows are bitwise what cropping
+    the whole F x N grid and indexing it gives, pad rows included, and the
+    cost scales with B, not with F x N."""
+    impl = ops.crop_gather_impl(pcfg.impl, frames_hq.shape)
+    CROP_IMPL_TRACED[(pcfg.impl, tuple(frames_hq.shape),
+                      idxs.shape[1])] = impl
     return ops.crop_gather(frames_hq, split.prop_boxes, idxs,
-                           out_hw=clf_cfg.crop_hw, impl=pcfg.impl)
+                           out_hw=clf_cfg.crop_hw, impl=impl)
 
 
 @functools.partial(jax.jit, static_argnames=("clf_cfg", "pcfg"))

@@ -4,6 +4,9 @@
   * ``"ref"``       pure-jnp oracle (CPU, dry-run lowering, XLA:TPU fallback)
   * ``"pallas"``    compiled Pallas TPU kernel (requires a real TPU)
   * ``"interpret"`` Pallas kernel body executed in interpret mode (CPU tests)
+
+``crop_gather`` alone picks by backend (:func:`crop_gather_impl`): ``"ref"``
+and ``"pallas"`` both run the kernel on a TPU and the oracle elsewhere.
 """
 from __future__ import annotations
 
@@ -133,15 +136,54 @@ def region_filter_mask_batch(proposals, prop_valid, accepted, acc_valid,
         frame_area=frame_area, interpret=(impl == "interpret"))
 
 
+# The crop kernel's VMEM, against the TPU compiler's default scoped-VMEM
+# limit: 16 MiB on a v5e (its error for a kernel over it reads "limit
+# 16.00M").  The kernel holds two (1, C, H, W) frame blocks (double-
+# buffered) and, in its body, up to 9 more (H, W) float32 planes.  The 9
+# is fitted from compiles for a described v5e with float32 3-channel frames
+# and 40x40 crops: 512x768 frames needed 19.78 MiB, 600x1000 29.47 MiB and
+# 256x2048 28.91 MiB, the two blocks plus 7.2, 6.6 and 8.5 planes; 512x512
+# frames compiled, with 40x40 and with 128x128 crops.  The estimate leaves
+# out the crop size: the (oh, H) and (W, ow) selections are small next to
+# the (H, W) planes while the crop is small next to the frame.
+SCOPED_VMEM_BYTES = 16 * 2 ** 20
+_CROP_BODY_PLANES = 9
+
+
+def _plane_bytes(h: int, w: int) -> int:
+    """VMEM bytes of one float32 (h, w) plane in (8, 128) tiles."""
+    return 4 * (-(-h // 8) * 8) * (-(-w // 128) * 128)
+
+
+def crop_gather_impl(impl: str, frame_shape) -> str:
+    """The implementation ``crop_gather`` runs for ``impl`` on frames of
+    ``frame_shape`` (F, H, W, C): ``"interpret"`` stays (tests only); on a
+    TPU, ``"ref"`` and ``"pallas"`` pick the compiled kernel (``"pallas"``)
+    where its VMEM fits the scoped limit, else the oracle (``"ref"``);
+    every other backend runs the oracle."""
+    if impl == "interpret":
+        return impl
+    if impl not in ("ref", "pallas") or jax.default_backend() != "tpu":
+        return "ref"
+    _, h, w, c = frame_shape
+    if (2 * c + _CROP_BODY_PLANES) * _plane_bytes(h, w) > SCOPED_VMEM_BYTES:
+        return "ref"
+    return "pallas"
+
+
 def crop_gather(frames, boxes, idxs, *, out_hw, impl: str = "ref"):
     """Compacted crop gather: (F,H,W,C) x (F,N,4) x (3,B) -> (B,oh,ow,C).
 
-    All impls share the bilinear program of ``ref.crop_positions`` and
-    ``ref.bilinear_sample``, so ref and interpret outputs are bit-identical
-    to gathering from the full shared crop grid (pallas too, where its
-    one-hot tap matmuls run at f32 precision).
+    Runs what :func:`crop_gather_impl` picks: the Pallas kernel on a TPU,
+    the jitted ``ref.crop_gather`` oracle elsewhere or where the kernel's
+    frame blocks would not fit VMEM.  Both share the bilinear program of
+    ``ref.crop_positions`` and ``ref.bilinear_sample``, so their outputs
+    are bit-identical to gathering from the full shared crop grid (the
+    kernel's one-hot tap matmuls run at ``HIGHEST`` precision, and
+    interpret mode is exact).
     """
-    if impl in ("ref", "ref_unchunked"):
+    impl = crop_gather_impl(impl, frames.shape)
+    if impl == "ref":
         return ref.crop_gather(frames, boxes, idxs, out_hw=out_hw)
     from repro.kernels import crop_gather as cg
     return cg.crop_gather(frames, boxes, idxs, out_hw=out_hw,

@@ -439,9 +439,9 @@ def crop_gather(frames: jax.Array,       # (F, H, W, C) HQ frames
                 boxes: jax.Array,        # (F, N, 4) proposal boxes
                 idxs: jax.Array,         # (>=2, B) compaction indices
                 *, out_hw: Tuple[int, int]) -> jax.Array:
-    """The compacted crop gather on ``impl="ref"``, which the compacted
-    classify stages serve with, and the Pallas kernel's oracle:
-    (B, oh, ow, C).
+    """The compacted crop gather: (B, oh, ow, C).  The served crop off
+    the TPU (and on it where the kernel's frame blocks would not fit
+    VMEM; ``ops.crop_gather_impl``), and the Pallas kernel's oracle.
 
     ``idxs[0]/idxs[1]`` are the flush's (frame, region) gather rows; pad
     rows carry the out-of-bounds frame index F and clip to the last frame

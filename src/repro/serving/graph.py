@@ -597,9 +597,12 @@ class GraphScheduler:
         # path (the reads that stall the accelerator feed; the per-chunk
         # result downloads happen later, at finalize, and are counted as
         # result_downloads); h2d_bytes counts the host bytes the serving
-        # path hands the device (_count_h2d)
+        # path hands the device (_count_h2d); crops_kernel counts the
+        # classified crops of flushes whose classify stage was traced onto
+        # the Pallas crop_gather kernel
         self.hot_path_stats = {"flushes": 0, "host_syncs": 0, "h2d_bytes": 0,
                                "result_downloads": 0, "crops_classified": 0,
+                               "crops_kernel": 0,
                                "crops_budget": 0, "inflight_peak": 0,
                                "ensemble_flushes": 0, "ensemble_uploads": 0,
                                "bundles_sealed": 0, "bundles_retained_peak": 0,
@@ -1369,6 +1372,10 @@ class GraphScheduler:
                 merged, _ = self.fog_batch_exec.run(
                     STAGE_CLASSIFY_BATCH, hq_batch, split_real, Ws,
                     idxs_dev, now=done, model_time=clf_time)
+            # the crop implementation the stage was traced with
+            if protocol_mod.crop_impl_traced(proto.pcfg, hq_host.shape,
+                                             bucket) not in (None, "ref"):
+                self.hot_path_stats["crops_kernel"] += bucket
 
         with self._span("vpaas.dispatch.results", flush=flush):
             # the whole flush's results travel as ONE device-side bundle

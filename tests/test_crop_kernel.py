@@ -1,8 +1,9 @@
 """Pallas crop_gather kernel: interpret-mode bitwise equality vs the jnp
-oracle and vs the shared-grid materialize-then-gather path, the
-compacted stages' ``impl="ref"`` crop step (bucket rows only, bitwise the
-grid's), plus the compacted classify stages under ``impl="interpret"`` —
-plain, ensemble, and empty-flush cases."""
+oracle and vs the shared-grid materialize-then-gather path, the choice of
+implementation by backend (``ops.crop_gather_impl``), the compacted
+stages' ``impl="ref"`` crop step (bucket rows only, bitwise the grid's),
+plus the compacted classify stages under ``impl="interpret"`` — plain,
+ensemble, and empty-flush cases, and a scheduler serving on the kernel."""
 import functools
 import re
 
@@ -15,6 +16,7 @@ from repro.configs.vpaas_video import ClassifierConfig, DetectorConfig
 from repro.core import protocol as pm
 from repro.core import regions as reg
 from repro.kernels import ops
+from repro.kernels import ref
 from repro.models import classifier as clf_mod
 from repro.models import detector as det_mod
 
@@ -73,8 +75,7 @@ def test_crop_gather_bitwise_sweep(f, n, hw, out_hw, valid_frac):
         jax.random.fold_in(KEY, f * 1000 + n), f, n, hw, valid_frac)
     idxs, n_valid, bucket = _idxs(pv)
     grid = np.asarray(_grid_gather(frames, boxes, idxs, out_hw=out_hw))
-    oracle = np.asarray(ops.crop_gather(frames, boxes, idxs, out_hw=out_hw,
-                                        impl="ref"))
+    oracle = np.asarray(ref.crop_gather(frames, boxes, idxs, out_hw=out_hw))
     kernel = np.asarray(ops.crop_gather(frames, boxes, idxs, out_hw=out_hw,
                                         impl="interpret"))
     assert grid.shape == (bucket, *out_hw, 3)
@@ -91,8 +92,7 @@ def test_crop_gather_oob_pad_rows_clip():
                                  [0, 0, 0, 0]], np.int32))
     out = np.asarray(ops.crop_gather(frames, boxes, idxs, out_hw=(8, 8),
                                      impl="interpret"))
-    want = np.asarray(ops.crop_gather(frames, boxes, idxs, out_hw=(8, 8),
-                                      impl="ref"))
+    want = np.asarray(ref.crop_gather(frames, boxes, idxs, out_hw=(8, 8)))
     np.testing.assert_array_equal(out, want)
     # a pad row's crop is the clipped (last-frame, region-0) crop
     np.testing.assert_array_equal(out[0], out[1])
@@ -115,6 +115,37 @@ def test_bucket_boundary_sizes():
         kernel = np.asarray(ops.crop_gather(frames, boxes, idxs,
                                             out_hw=(8, 8), impl="interpret"))
         np.testing.assert_array_equal(kernel, grid)
+
+
+# ---------------------------------------------------------------------------
+# the implementation ops.crop_gather runs, by backend and frame size
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("backend,impl,frame_shape,want", [
+    ("tpu", "ref", (8, 128, 128, 3), "pallas"),     # the served cell's frames
+    ("tpu", "pallas", (8, 128, 128, 3), "pallas"),
+    ("tpu", "interpret", (8, 128, 128, 3), "interpret"),
+    ("tpu", "ref", (8, 720, 1280, 3), "ref"),       # HQ 720p: over VMEM
+    ("tpu", "pallas", (8, 720, 1280, 3), "ref"),
+    ("cpu", "ref", (8, 128, 128, 3), "ref"),
+    ("cpu", "pallas", (8, 128, 128, 3), "ref"),
+    ("cpu", "interpret", (8, 128, 128, 3), "interpret"),
+    ("gpu", "ref", (8, 128, 128, 3), "ref"),
+])
+def test_crop_gather_impl(monkeypatch, backend, impl, frame_shape, want):
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert ops.crop_gather_impl(impl, frame_shape) == want
+
+
+def test_crop_gather_impl_vmem_budget(monkeypatch):
+    """The kernel is chosen up to the scoped-VMEM budget and not past it:
+    two frame blocks and the body's planes, in (8, 128) tiles."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    # 15 planes (2 x 3 channels + 9) of 512 x 512 float32: 15 MiB
+    assert ops.crop_gather_impl("ref", (8, 512, 512, 3)) == "pallas"
+    # 520 rows tile to 520, 520 columns to 640: 19.04 MiB
+    assert ops.crop_gather_impl("ref", (8, 520, 520, 3)) == "ref"
+    assert ops._plane_bytes(520, 520) == 4 * 520 * 640
+    assert ops._plane_bytes(1, 1) == 4 * 8 * 128
 
 
 # ---------------------------------------------------------------------------
@@ -286,3 +317,64 @@ def test_crop_and_resize_matches_map_coordinates():
         want = np.asarray(jnp.stack([one(b) for b in boxes]))
         got = np.asarray(reg.crop_and_resize(frame, boxes, (oh, ow)))
     np.testing.assert_array_equal(got, want)
+
+
+# ---------------------------------------------------------------------------
+# a scheduler serving its crops on the kernel
+# ---------------------------------------------------------------------------
+RESULT_FIELDS = ("boxes", "labels", "valid", "source", "fog_features",
+                 "prop_boxes", "prop_valid", "fog_scores")
+
+
+def _serve_two_streams(models):
+    """Serve 2 streams x 2 chunks in shared flushes; the scheduler's
+    counters and every chunk's result fields, in stream order."""
+    from repro.core.coordinator import MultiStreamCoordinator
+    from repro.video import synthetic
+    det_params, clf_params = models
+    streams = [[synthetic.make_chunk(np.random.default_rng(70 + 10 * i + j),
+                                     "traffic", num_frames=2, hw=(32, 32))
+                for j in range(2)] for i in range(2)]
+    multi = MultiStreamCoordinator(pm.HighLowProtocol(DET, CLF), det_params,
+                                   clf_params, streams, max_batch_chunks=2,
+                                   batch_window=0.05)
+    multi.run(learn=False)
+    sched = multi.scheduler
+    fields = [np.asarray(getattr(res, f))
+              for name in sorted(sched.streams)
+              for _, res, _ in sched.streams[name].results
+              for f in RESULT_FIELDS]
+    return dict(sched.hot_path_stats), fields
+
+
+@pytest.fixture
+def fresh_traces():
+    """The crop implementation is chosen while a stage is traced: drop the
+    traced programs and their recorded choices before and after, so that a
+    choice the test forces is traced anew and does not outlive the test."""
+    jax.clear_caches()
+    pm.CROP_IMPL_TRACED.clear()
+    yield
+    jax.clear_caches()
+    pm.CROP_IMPL_TRACED.clear()
+
+
+def test_scheduler_counts_kernel_crops(models, monkeypatch, fresh_traces):
+    """On the kernel (the chooser forced to ``"interpret"``) every crop a
+    flush classifies counts in ``crops_kernel`` and the results are bitwise
+    those of the oracle run, which counts none."""
+    oracle_stats, oracle_fields = _serve_two_streams(models)
+    assert set(pm.CROP_IMPL_TRACED.values()) == {"ref"}
+    assert oracle_stats["crops_classified"] > 0
+    assert oracle_stats["crops_kernel"] == 0
+    jax.clear_caches()
+    pm.CROP_IMPL_TRACED.clear()
+    monkeypatch.setattr(ops, "crop_gather_impl",
+                        lambda impl, frame_shape: "interpret")
+    stats, fields = _serve_two_streams(models)
+    assert set(pm.CROP_IMPL_TRACED.values()) == {"interpret"}
+    assert stats["crops_kernel"] == stats["crops_classified"]
+    assert stats["crops_classified"] == oracle_stats["crops_classified"]
+    assert len(fields) == len(oracle_fields) == 4 * len(RESULT_FIELDS)
+    for got, want in zip(fields, oracle_fields):
+        np.testing.assert_array_equal(got, want)

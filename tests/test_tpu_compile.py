@@ -78,6 +78,17 @@ def shapes(one_chip, no_persistent_cache):
                                        jnp.float32)))
 
 
+@pytest.fixture
+def tpu_backend(monkeypatch):
+    """``ops.crop_gather`` picks its implementation by ``jax.default_backend``,
+    which here is the CPU: answer as the chip does.  The choice is made
+    while a stage is traced, so traced programs are dropped around it."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
+
+
 def _has_kernel(compiled) -> bool:
     return "tpu_custom_call" in compiled.as_text()
 
@@ -92,13 +103,47 @@ def test_detect_split_compiles(shapes, impl):
 
 
 @pytest.mark.parametrize("impl", ["ref", "pallas"])
-def test_classify_compacted_compiles(shapes, impl):
+def test_classify_compacted_compiles(shapes, tpu_backend, impl):
     pcfg = pm.ProtocolConfig(impl=impl)
     compiled = jax.jit(pm.classify_compacted, static_argnums=(0, 1)).lower(
         CLASSIFIER, pcfg, shapes["clf_p"], shapes["Ws"], shapes["frames"],
         shapes["split"], shapes["idxs"]).compile()
-    # the pallas impl gathers the bucket's crops with the crop kernel
-    assert _has_kernel(compiled) == (impl == "pallas")
+    # on a TPU both impls gather the bucket's crops with the crop kernel
+    assert _has_kernel(compiled)
+
+
+def test_classify_compacted_ensemble_compiles(shapes, tpu_backend):
+    """The per-site learning stage crops with the same kernel on "ref"."""
+    d1 = CLASSIFIER.feature_dim + 1
+    snaps = jax.ShapeDtypeStruct((4, 2, d1, CLASSIFIER.num_classes),
+                                 jnp.float32,
+                                 sharding=shapes["frames"].sharding)
+    omegas = jax.ShapeDtypeStruct((4, 2), jnp.float32,
+                                  sharding=shapes["frames"].sharding)
+    compiled = jax.jit(pm.classify_compacted_ensemble,
+                       static_argnums=(0, 1)).lower(
+        CLASSIFIER, pm.ProtocolConfig(impl="ref"), shapes["clf_p"], snaps,
+        omegas, shapes["frames"], shapes["split"], shapes["idxs"]).compile()
+    assert _has_kernel(compiled)
+
+
+@pytest.mark.parametrize("hw,kernel", [((512, 512), True),
+                                       ((720, 1280), False)])
+def test_crop_gather_vmem_choice_compiles(one_chip, no_persistent_cache,
+                                          tpu_backend, hw, kernel):
+    """At the edge of the VMEM budget the kernel compiles; HQ 720p frames,
+    whose blocks the kernel could not hold, fall back to the oracle and
+    compile too (the kernel there runs out of scoped VMEM)."""
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    frames = on_chip((8, *hw, 3), jnp.float32)
+    assert (ops.crop_gather_impl("ref", frames.shape) == "pallas") == kernel
+    compiled = jax.jit(lambda f, b, i: ops.crop_gather(
+        f, b, i, out_hw=CLASSIFIER.crop_hw)).lower(
+        frames, on_chip((8, 256, 4), jnp.float32),
+        on_chip((3, 64), jnp.int32)).compile()
+    assert _has_kernel(compiled) == kernel
 
 
 def test_onevsall_scores_compiles(shapes):
